@@ -11,6 +11,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import FlowServeEngine
 
 
@@ -20,6 +21,7 @@ def main() -> None:
     ap.add_argument("--dp-groups", type=int, default=2)
     ap.add_argument("--max-new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch + "-smoke")
     print(f"arch={cfg.name} family={cfg.family} "

@@ -15,10 +15,12 @@ sys.path.insert(0, "src")
 
 from repro.configs import get_config
 from repro.core import DisaggregatedPD
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.request import Request
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("deepseek-moe-16b-smoke")
     print(f"serving {cfg.name}: MoE {cfg.moe.num_experts}e "
           f"top-{cfg.moe.top_k} + {cfg.moe.num_shared_experts} shared")

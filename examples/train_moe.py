@@ -21,7 +21,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import ATTN, MLP, MOE, ModelConfig, MoEConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train import (AdamWConfig, DataConfig, TrainConfig, Trainer)
+    enable_compile_cache()
 
     # ~100M-param fine-grained MoE (deepseek-moe style, scaled down)
     cfg = ModelConfig(

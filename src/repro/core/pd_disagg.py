@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.launch.sharding import init_params
 from repro.models.mesh_ctx import MeshCtx, make_smoke_ctx
 from repro.models.transformer import build_model
 from repro.serving.backend import JAXBackend
@@ -137,7 +138,8 @@ class DisaggregatedPD:
         ctx = ctx or make_smoke_ctx()
         self.model = build_model(cfg, ctx)
         self.params = (params if params is not None
-                       else self.model.init(jax.random.PRNGKey(seed)))
+                       else init_params(self.model,
+                                        jax.random.PRNGKey(seed)))
         self.tokenizer = ByteTokenizer()
 
         # pod-pooled prefix KV (kv_pool): one directory spans every

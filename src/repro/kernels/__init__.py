@@ -11,8 +11,9 @@ interpret mode against the oracle over shape/dtype sweeps
   decode_attention — flash-decoding GQA over the KV cache (Fig. 20 hot loop)
   quant_dispatch   — fused token-wise INT8 quantization for dispatch (§3.2)
   collect          — EPLB expert-load histogram (§4.5 step 1)
-  route_pack       — fused dispatch packing: capacity rank + INT8 quantize
-                     + bucket scatter in one streaming pass (§3.2/§4.7)
+  route_pack       — dispatch packing: capacity rank on the scalar core and
+                     one row DMA per kept assignment into its capacity
+                     bucket, INT8 quantize ahead of it (§3.2/§4.7)
 
 Wrapper ``interpret`` arguments default to ``None`` = auto: interpret
 only when the active JAX backend is CPU (``kernels/runtime.py``), so the

@@ -30,6 +30,38 @@ from repro.kernels.gmm.ref import gmm_ref, placement_gmm_ref
 from repro.kernels.runtime import on_cpu, resolve_interpret
 
 
+# the TPU compiler's default scoped-VMEM limit (16 MiB on v5e) less room
+# for the kernel's own temporaries (the [bc, bf] gate/up/hidden tiles)
+_VMEM_BUDGET = 14 * 2**20
+
+
+def _vmem_bytes(bc: int, bf: int, d: int, x_bytes: int,
+                w_bytes: int) -> int:
+    """VMEM one grid step holds: double-buffered token block, gate/up/
+    down weight blocks and f32 output block, plus the f32 accumulator."""
+    return (2 * bc * d * x_bytes + 2 * 3 * d * bf * w_bytes
+            + 2 * bc * d * 4 + bc * d * 4)
+
+
+def _blocks(C: int, d: int, f: int, x_bytes: int, w_bytes: int):
+    """Largest (bc, bf) within :data:`_VMEM_BUDGET`: ``bc`` a multiple of
+    8 up to 128 (capacity is padded up to a multiple of it), ``bf`` a
+    lane-aligned divisor of ``f`` up to 512 (the whole ``f`` when it is
+    not a multiple of 128). The ff blocks shrink first: they cost
+    weight-block VMEM without changing how often weights stream."""
+    bfs = [b for b in range(min(512, f) // 128 * 128, 0, -128)
+           if f % b == 0] or [f]
+    bc = min(128, -(-C // 8) * 8)
+    while True:
+        for bf in bfs:
+            if _vmem_bytes(bc, bf, d, x_bytes, w_bytes) <= _VMEM_BUDGET:
+                return bc, bf
+        if bc == 8:
+            raise ValueError(
+                f"gmm: no block of d={d}, f={f} fits the VMEM budget")
+        bc = max(8, bc // 2 // 8 * 8)
+
+
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def _dispatch(buckets, we_gate, we_up, we_down, phys_owner, *,
               use_pallas, interpret):
@@ -40,15 +72,11 @@ def _dispatch(buckets, we_gate, we_up, we_down, phys_owner, *,
                                  phys_owner)
     E, C, d = buckets.shape
     f = we_gate.shape[-1]
-    padc = (-C) % 8
+    bc, bf = _blocks(C, d, f, buckets.dtype.itemsize,
+                     we_gate.dtype.itemsize)
+    padc = (-C) % bc
     if padc:
         buckets = jnp.pad(buckets, ((0, 0), (0, padc), (0, 0)))
-    bc = min(128, C + padc)
-    while (C + padc) % bc:
-        bc //= 2
-    bf = min(512, f)
-    while f % bf:
-        bf //= 2
     if phys_owner is None:
         out = _gmm(buckets, we_gate, we_up, we_down, bc=bc, bf=bf,
                    interpret=interpret)

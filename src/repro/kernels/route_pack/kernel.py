@@ -1,26 +1,28 @@
-"""Pallas TPU kernel: fused route-pack (§3.2 dispatch packing).
+"""Pallas TPU kernel: route-pack (§3.2 dispatch packing).
 
 One streaming pass over the routed assignments replaces the
 O(N·E)-memory ``one_hot``/``cumsum``/``scatter`` chain that
 ``xccl/routing.py`` and ``models/ffn.py`` used to build capacity
-buckets: token blocks flow HBM→VMEM once; a per-destination running
-count lives in VMEM scratch across grid steps (the cumsum never
-materializes a [N, E] tensor in HBM); the per-token INT8 quantization
-(§4.7 communication quantization) happens while the payload block sits
-in VMEM; and kept rows are scattered straight into the destination
-capacity buckets. On Ascend this is the work the fused dispatch kernel
-does inside the communication op — quantize + pack at zero extra HBM
-passes.
+buckets. The capacity rank is a scalar walk: assignment blocks land in
+SMEM, a per-destination running count lives in SMEM scratch across
+grid steps (no [N, E] tensor anywhere), and each kept assignment
+starts one row DMA straight from the payload in HBM to its
+``(dest, rank)`` bucket row in HBM. No payload byte passes through
+VMEM, so the bucket plane is bounded by HBM, not by VMEM: a 2,048-token
+deepseek-moe-16b prefill chunk packs 64 × 288 × 2048 bf16 ≈ 75 MB.
 
-Layout contract (``ops.py`` pads/reshapes):
+Layout contract (``ops.py`` pads and handles the side payloads):
 
-* ``x``      [Tp, d]   payload rows; assignment ``r`` reads row ``r//k``
-  (the top-k repeat is an in-VMEM gather, never materialized as [N, d]).
-* ``dest``   [Np, 1]   destination bucket per assignment; rows carrying
+* ``x``       [T, d]   payload rows in HBM, viewed as [T, d/128, 128];
+  assignment ``r`` reads row ``r // k`` (the top-k repeat is a DMA
+  source index, never a materialized [N, d] gather).
+* ``dest``    [Np]     destination bucket per assignment; rows carrying
   ``dest >= n_dest`` are padding and consume no rank slots.
-* ``valid``  [Np, 1]   0 masks an assignment out of ``keep`` (it still
-  consumes a rank slot of its safe destination, matching the reference
+* ``valid``   [Np]     0 masks an assignment out of ``keep`` (it still
+  consumes a rank slot of its destination, matching the reference
   ``capacity_rank(where(valid, dest, 0))`` semantics).
+* ``zeros``   [n_dest, C, d/128, 128]  aliased to the bucket output:
+  rows no assignment lands in keep their zero fill.
 """
 from __future__ import annotations
 
@@ -29,132 +31,86 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _vmem_spec(shape, index_map):
-    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
-
-
-def _kernel(x_ref, dest_ref, valid_ref, buckets_ref, scales_ref, eids_ref,
-            rank_ref, keep_ref, counts_ref, *, k: int, n_dest: int,
-            capacity: int, quantize: bool, has_eid: bool, eid_ref=None):
+def _kernel(dest_ref, valid_ref, x_hbm, zeros_hbm, buckets_hbm, rank_ref,
+            keep_ref, counts_ref, sem, *, k: int, n_dest: int,
+            capacity: int):
+    del zeros_hbm                         # aliased to buckets_hbm
     i = pl.program_id(0)
     bn = dest_ref.shape[0]
 
-    # ---- first block: zero the running counts + fill the buckets ------
     @pl.when(i == 0)
     def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-        buckets_ref[...] = jnp.zeros_like(buckets_ref)
-        if quantize:
-            scales_ref[...] = jnp.zeros_like(scales_ref)
-        if has_eid:
-            eids_ref[...] = jnp.full_like(eids_ref, -1)
+        def zero(j, c):
+            counts_ref[j] = 0
+            return c
+        jax.lax.fori_loop(0, n_dest, zero, 0)
 
-    # ---- streaming capacity rank (block cumsum + carried counts) ------
-    dest = dest_ref[...]                                   # [bn, 1] int32
-    valid = valid_ref[...]                                 # [bn, 1] int32
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_dest), 1)
-    onehot = (dest == iota).astype(jnp.int32)              # [bn, n_dest]
-    prev = counts_ref[0, :]                                # [n_dest]
-    csum = jnp.cumsum(onehot, axis=0)
-    rank_mat = csum - 1 + prev[None, :]
-    my_rank = jnp.sum(onehot * rank_mat, axis=1)           # [bn]
-    counts_ref[0, :] = prev + csum[-1, :]
-    keep = (my_rank < capacity) & (valid[:, 0] > 0)
-    rank_ref[...] = my_rank[:, None]
-    keep_ref[...] = keep.astype(jnp.int32)[:, None]
+    def row_copy(src_row, dst, rk):
+        return pltpu.make_async_copy(x_hbm.at[src_row],
+                                     buckets_hbm.at[dst, rk], sem)
 
-    # ---- fused INT8 quantization of the payload block -----------------
-    x = x_ref[...]                                         # [bn//k, d]
-    if quantize:
-        xf = x.astype(jnp.float32)
-        amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-        # reciprocal multiply: bit-identical across XLA fusion contexts
-        scale = jnp.maximum(amax, 1e-8) * (1.0 / 127.0)
-        vals = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
-        scales = scale[:, 0]
-    else:
-        vals = x.astype(buckets_ref.dtype)
-        scales = None
+    def assign(r, n_started):
+        dst = dest_ref[r]
+        real = dst < n_dest
+        safe = jnp.where(real, dst, 0)
+        rk = counts_ref[safe]
+        counts_ref[safe] = rk + real.astype(jnp.int32)
+        keep = real & (rk < capacity) & (valid_ref[r] > 0)
+        rank_ref[r] = jnp.where(real, rk, 0)
+        keep_ref[r] = keep.astype(jnp.int32)
 
-    # ---- scatter kept rows into the capacity buckets ------------------
-    def scatter_row(r, _):
-        @pl.when(keep[r])
+        @pl.when(keep)
         def _():
-            d_idx = dest[r, 0]
-            rk = my_rank[r]
-            row = jax.lax.dynamic_index_in_dim(vals, r // k, axis=0,
-                                               keepdims=False)
-            buckets_ref[d_idx, rk, :] = row
-            if quantize:
-                scales_ref[d_idx, rk] = jax.lax.dynamic_index_in_dim(
-                    scales, r // k, keepdims=False)
-            if has_eid:
-                eids_ref[d_idx, rk] = eid_ref[r, 0]
-        return 0
+            row_copy((i * bn + r) // k, safe, rk).start()
+        return n_started + keep.astype(jnp.int32)
 
-    jax.lax.fori_loop(0, bn, scatter_row, 0)
+    n_started = jax.lax.fori_loop(0, bn, assign, 0)
+
+    def drain(j, c):
+        row_copy(0, 0, 0).wait()          # every copy moves one row
+        return c
+    jax.lax.fori_loop(0, n_started, drain, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_dest", "capacity",
-                                             "quantize", "has_eid", "bn",
-                                             "interpret"))
-def route_pack_kernel(x, dest, valid, eid, *, k: int, n_dest: int,
-                      capacity: int, quantize: bool, has_eid: bool,
-                      bn: int, interpret: bool = True):
+                                             "bn", "interpret"))
+def route_pack_kernel(x, dest, valid, *, k: int, n_dest: int,
+                      capacity: int, bn: int, interpret: bool = True):
     """Pre-padded entry (``ops.py`` handles padding/unpadding).
 
-    x [Tp, d]; dest/valid/eid [Np, 1] with Np = Tp * k, Np % bn == 0.
-    Returns (buckets [n_dest, C, d], scales [n_dest, C] | None,
-    eids [n_dest, C] | None, rank [Np], keep [Np] bool).
-    """
-    Tp, d = x.shape
+    x [T, d]; dest/valid [Np] int32 with Np % bn == 0 and every real
+    assignment ``r < T * k``. Returns (buckets [n_dest, C, d] of
+    x.dtype, rank [Np] int32, keep [Np] int32)."""
+    T, d = x.shape
     Np = dest.shape[0]
-    assert Np == Tp * k and Np % bn == 0 and bn % k == 0
-    grid = (Np // bn,)
-    out_dtype = jnp.int8 if quantize else x.dtype
-
-    whole3 = _vmem_spec((n_dest, capacity, d), lambda i: (0, 0, 0))
-    whole2 = _vmem_spec((n_dest, capacity), lambda i: (0, 0))
-    blk_assign = _vmem_spec((bn, 1), lambda i: (i, 0))
-    out_shapes = (
-        jax.ShapeDtypeStruct((n_dest, capacity, d), out_dtype),   # buckets
-        jax.ShapeDtypeStruct((n_dest, capacity), jnp.float32),    # scales
-        jax.ShapeDtypeStruct((n_dest, capacity), jnp.int32),      # eids
-        jax.ShapeDtypeStruct((Np, 1), jnp.int32),                 # rank
-        jax.ShapeDtypeStruct((Np, 1), jnp.int32),                 # keep
-    )
-    out_specs = (whole3, whole2, whole2, blk_assign, blk_assign)
-    scratch = [pltpu.VMEM((1, n_dest), jnp.int32)]
-
-    kern = functools.partial(_kernel, k=k, n_dest=n_dest,
-                             capacity=capacity, quantize=quantize,
-                             has_eid=has_eid)
-    if has_eid:
-        def kern_with_eid(x_ref, dest_ref, valid_ref, eid_ref, *outs):
-            return kern(x_ref, dest_ref, valid_ref, *outs,
-                        eid_ref=eid_ref)
-        body = kern_with_eid
-        in_specs = [_vmem_spec((bn // k, d), lambda i: (i, 0)),
-                    blk_assign, blk_assign, blk_assign]
-        args = (x, dest, valid, eid)
-    else:
-        body = kern
-        in_specs = [_vmem_spec((bn // k, d), lambda i: (i, 0)),
-                    blk_assign, blk_assign]
-        args = (x, dest, valid)
-
-    buckets, scales, eids, rank, keep = pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        scratch_shapes=scratch,
+    assert Np % bn == 0
+    # each payload row travels as a whole [d/128, 128] tile slab under a
+    # major (untiled) index: HBM tiles span 8-32 rows, so a single row of
+    # a plain [T, d] array is not addressable by DMA
+    lanes = 128 if d % 128 == 0 else d
+    row = (d // lanes, lanes)
+    blk = pl.BlockSpec((bn,), lambda i: (i,), memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    zeros = jnp.zeros((n_dest, capacity) + row, x.dtype)
+    buckets, rank, keep = pl.pallas_call(
+        functools.partial(_kernel, k=k, n_dest=n_dest, capacity=capacity),
+        grid=(Np // bn,),
+        in_specs=[blk, blk, hbm, hbm],
+        out_specs=(hbm, blk, blk),
+        out_shape=(
+            jax.ShapeDtypeStruct((n_dest, capacity) + row, x.dtype),
+            jax.ShapeDtypeStruct((Np,), jnp.int32),
+            jax.ShapeDtypeStruct((Np,), jnp.int32),
+        ),
+        scratch_shapes=[pltpu.SMEM((n_dest,), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={3: 0},
+        # the running counts carry across blocks: strictly sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*args)
-    return (buckets, scales if quantize else None,
-            eids if has_eid else None, rank[:, 0], keep[:, 0] > 0)
+    )(dest, valid, x.reshape((T,) + row), zeros)
+    return buckets.reshape(n_dest, capacity, d), rank, keep

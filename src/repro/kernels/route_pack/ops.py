@@ -1,7 +1,7 @@
 """jit'd wrapper: pads the assignment dim, exposes use_pallas switch.
 
 ``use_pallas=None`` (default) picks the execution automatically: the
-compiled Pallas kernel off-CPU, the fused-equivalent jnp oracle on CPU
+compiled Pallas kernel off-CPU, the equivalent jnp oracle on CPU
 (where the interpreter would only add overhead inside jitted serving
 steps). Tests pin ``use_pallas=True`` to validate the kernel in
 interpret mode against the oracle bit-for-bit.
@@ -22,8 +22,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.route_pack.kernel import route_pack_kernel
-from repro.kernels.route_pack.ref import RoutePack, route_pack_ref
+from repro.kernels.route_pack.ref import (RoutePack, quantize_rows,
+                                          route_pack_ref, scatter_rows)
 from repro.kernels.runtime import on_cpu, resolve_interpret
+
+# assignments per grid step: the SMEM block of dest/valid/rank/keep (XLA
+# tiles 1-D int32 arrays by 1024, so a multi-block split uses 1024)
+_BLOCK = 1024
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_dest", "capacity",
@@ -34,26 +39,32 @@ def _dispatch(x, dest, valid, eid, *, k, n_dest, capacity, quantize,
     if not use_pallas:
         return route_pack_ref(x, dest, valid, eid, k=k, n_dest=n_dest,
                               capacity=capacity, quantize=quantize)
-    T, d = x.shape
     N = dest.shape[0]
-    bn = k * max(1, 128 // k)
+    bn = min(_BLOCK, -(-N // 128) * 128)
     pad_n = (-N) % bn
-    has_eid = eid is not None
     if valid is None:
         valid = jnp.ones((N,), jnp.int32)
     dest_p = jnp.concatenate(
         [dest.astype(jnp.int32), jnp.full((pad_n,), n_dest, jnp.int32)])
     valid_p = jnp.concatenate(
         [valid.astype(jnp.int32), jnp.zeros((pad_n,), jnp.int32)])
-    eid_p = (jnp.concatenate([eid.astype(jnp.int32),
-                              jnp.zeros((pad_n,), jnp.int32)])
-             if has_eid else jnp.zeros((N + pad_n,), jnp.int32))
-    x_p = jnp.pad(x, ((0, pad_n // k), (0, 0)))
-    buckets, scales, eids, rank, keep = route_pack_kernel(
-        x_p, dest_p[:, None], valid_p[:, None], eid_p[:, None],
-        k=k, n_dest=n_dest, capacity=capacity, quantize=quantize,
-        has_eid=has_eid, bn=bn, interpret=interpret)
-    return RoutePack(buckets, scales, eids, rank[:N], keep[:N])
+    # per-row INT8 quantization is row-local, so quantizing the T token
+    # rows before the pack equals quantizing each assignment's copy
+    payload, tok_scales = quantize_rows(x) if quantize else (x, None)
+    buckets, rank, keep = route_pack_kernel(
+        payload, dest_p, valid_p, k=k, n_dest=n_dest, capacity=capacity,
+        bn=bn, interpret=interpret)
+    rank, keep = rank[:N], keep[:N] > 0
+    # the [n_dest, C] side planes are tiny: scatter them by the kernel's
+    # rank/keep exactly as the reference does
+    tok_of = jnp.arange(N) // k
+    scales = (None if tok_scales is None else
+              scatter_rows(tok_scales[tok_of], dest, rank, keep, n_dest,
+                           capacity))
+    eids = (None if eid is None else
+            scatter_rows(eid.astype(jnp.int32), dest, rank, keep, n_dest,
+                         capacity, fill=-1))
+    return RoutePack(buckets, scales, eids, rank, keep)
 
 
 def placement_route(dest: jax.Array, positions: jax.Array,
@@ -100,12 +111,14 @@ def placement_route_local(dest: jax.Array, positions: jax.Array,
 def fused_route_pack(x, dest, valid=None, eid=None, *, k: int = 1,
                      n_dest: int, capacity: int, quantize: bool = False,
                      use_pallas=None, interpret=None) -> RoutePack:
-    """Fused capacity rank + INT8 quantize + bucket scatter.
+    """Capacity rank + INT8 quantize + bucket scatter in one jitted op
+    (the rank and the row scatter are the Pallas kernel).
 
     x [T, d] payload rows (assignment ``r`` carries row ``r // k``);
-    dest [N = T*k] int32 destinations already clamped to [0, n_dest)
-    (rows masked out by ``valid`` still consume a rank slot of their
-    clamped destination, exactly like the reference chain); eid [N]
+    dest [N = T*k] int32 destinations in [0, n_dest]: ``n_dest`` marks
+    an assignment that takes no slot (rank 0, never kept), while rows
+    masked out by ``valid`` with a real destination still consume a rank
+    slot of it, exactly like the reference chain; eid [N]
     optional int32 side payload bucketed with fill -1. Under EPLB
     placement, ``dest`` carries PHYSICAL slot ids (callers remap via
     :func:`placement_route`) and ``n_dest`` is the physical slot count.

@@ -24,13 +24,18 @@ class RoutePack(NamedTuple):
 
 
 def _capacity_rank(dest, n_dest, capacity):
+    """FIFO rank within each destination; ``dest == n_dest`` takes no
+    slot (rank 0, never kept)."""
     onehot = jax.nn.one_hot(dest, n_dest, dtype=jnp.int32)
     ranks = jnp.cumsum(onehot, axis=0) - 1
-    my_rank = jnp.take_along_axis(ranks, dest[:, None], axis=1)[:, 0]
-    return my_rank, my_rank < capacity
+    real = dest < n_dest
+    my_rank = jnp.take_along_axis(ranks, jnp.where(real, dest, 0)[:, None],
+                                  axis=1)[:, 0]
+    my_rank = jnp.where(real, my_rank, 0)
+    return my_rank, real & (my_rank < capacity)
 
 
-def _scatter(values, dest, rank, keep, n_dest, capacity, fill=0):
+def scatter_rows(values, dest, rank, keep, n_dest, capacity, fill=0):
     safe_rank = jnp.where(keep, rank, capacity)
     buf = jnp.full((n_dest, capacity + 1) + values.shape[1:], fill,
                    values.dtype)
@@ -38,7 +43,7 @@ def _scatter(values, dest, rank, keep, n_dest, capacity, fill=0):
     return buf[:, :capacity]
 
 
-def _quantize(x):
+def quantize_rows(x):
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-8) * (1.0 / 127.0)
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
@@ -59,13 +64,14 @@ def route_pack_ref(x, dest, valid=None, eid=None, *, k: int = 1,
     payload = x[tok_of]
     scales = None
     if quantize:
-        qv, sc = _quantize(payload)
-        buckets = _scatter(qv, dest, rank, keep, n_dest, capacity)
-        scales = _scatter(sc, dest, rank, keep, n_dest, capacity)
+        qv, sc = quantize_rows(payload)
+        buckets = scatter_rows(qv, dest, rank, keep, n_dest, capacity)
+        scales = scatter_rows(sc, dest, rank, keep, n_dest, capacity)
     else:
-        buckets = _scatter(payload, dest, rank, keep, n_dest, capacity)
+        buckets = scatter_rows(payload, dest, rank, keep, n_dest,
+                               capacity)
     eids = None
     if eid is not None:
-        eids = _scatter(eid.astype(jnp.int32), dest, rank, keep, n_dest,
-                        capacity, fill=-1)
+        eids = scatter_rows(eid.astype(jnp.int32), dest, rank, keep,
+                            n_dest, capacity, fill=-1)
     return RoutePack(buckets, scales, eids, rank.astype(jnp.int32), keep)

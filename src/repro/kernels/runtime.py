@@ -20,5 +20,8 @@ def default_interpret() -> bool:
     return on_cpu()
 
 
-def resolve_interpret(interpret) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
+def resolve_interpret(interpret):
+    """``None`` → :func:`default_interpret`; anything else passes through
+    to ``pallas_call`` (a bool, or ``pltpu.InterpretParams`` for the
+    TPU-semantics interpreter)."""
+    return default_interpret() if interpret is None else interpret

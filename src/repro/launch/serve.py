@@ -3,6 +3,9 @@
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
         --smoke --mode pd-disagg --prompt "hello" --prompt "world"
 
+``--full`` serves the published-width config (random weights);
+internlm2-1.8b fits one TPU v5e chip.
+
 Modes: ``colocated`` (single FlowServe TE) and ``pd-disagg`` (§5.1
 pipeline: prefill TEs + decode TE over DistFlow).
 """
@@ -16,14 +19,19 @@ def main() -> None:
     ap.add_argument("--arch", default="internlm2-1.8b")
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full", dest="smoke", action="store_false",
-                    help="use the full config (needs matching hardware)")
+                    help="use the published-width config")
     ap.add_argument("--mode", choices=["colocated", "pd-disagg"],
                     default="colocated")
     ap.add_argument("--prompt", action="append", default=None)
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--dp-groups", type=int, default=2)
     ap.add_argument("--temperature", type=float, default=0.0)
+    ap.add_argument("--max-len", type=int, default=256,
+                    help="KV-cache length per slot (prompt + new tokens)")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get_config
     cfg = get_config(args.arch + ("-smoke" if args.smoke else ""))
@@ -32,7 +40,7 @@ def main() -> None:
     if args.mode == "colocated":
         from repro.serving import FlowServeEngine
         eng = FlowServeEngine(cfg, n_dp_groups=args.dp_groups,
-                              max_batch=2, max_len=256)
+                              max_batch=2, max_len=args.max_len)
         outs = eng.generate(prompts, args.max_new_tokens,
                             temperature=args.temperature)
         for p, o in zip(prompts, outs):
@@ -43,7 +51,7 @@ def main() -> None:
         from repro.serving.request import Request
         pd = DisaggregatedPD(cfg, n_prefill_te=2, n_decode_te=1,
                              dp_per_te=args.dp_groups, max_batch=2,
-                             max_len=256)
+                             max_len=args.max_len)
         reqs = [Request(prompt=p, max_new_tokens=args.max_new_tokens,
                         temperature=args.temperature, ignore_eos=True)
                 for p in prompts]

@@ -141,6 +141,16 @@ def param_shardings(params_shape: PyTree, mesh: Mesh,
                         param_pspecs(params_shape, mesh, rules))
 
 
+def init_params(model, key) -> PyTree:
+    """Initialise ``model``'s params from ``key`` in ONE jitted call,
+    each leaf created in place with its :func:`param_shardings` layout on
+    the model's mesh (experts split over the EP axis, heads and ff over
+    the TP axis), so no leaf is ever materialised whole on one device."""
+    shapes = jax.eval_shape(model.init, key)
+    return jax.jit(model.init, out_shardings=param_shardings(
+        shapes, model.ctx.mesh))(key)
+
+
 # ---------------------------------------------------------------------------
 # Cache shardings
 # ---------------------------------------------------------------------------

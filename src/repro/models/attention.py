@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig, MLAConfig
 from repro.models.common import (apply_rope, blockwise_attention, dense_init,
@@ -303,7 +302,7 @@ def decode_attention_distributed(
         return out, ck_stack, cv_stack
 
     cache_spec = P(None, ctx.bspec, seq_ax, None, None)
-    out, nk, nv = shard_map(
+    out, nk, nv = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(ctx.bspec, None, None, None),
                   P(ctx.bspec, None, None, None),
@@ -311,7 +310,7 @@ def decode_attention_distributed(
                   cache_spec, cache_spec, batch_spec, P()),
         out_specs=(P(ctx.bspec, None, None, None),
                    cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, k_new, v_new, ref.stack["k"], ref.stack["v"], positions,
       jnp.asarray(ref.idx, jnp.int32))
     return out, ref.with_stack({"k": nk, "v": nv})
@@ -550,7 +549,7 @@ def _mla_decode_distributed(q_lat, q_rope, ckv_new, krope_new, ref,
 
     bspec = P(ctx.bspec)
     c_spec = P(None, ctx.bspec, seq_ax, None)
-    out, nckv, nkrope = shard_map(
+    out, nckv, nkrope = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(ctx.bspec, None, None, None),
                   P(ctx.bspec, None, None, None),
@@ -558,7 +557,7 @@ def _mla_decode_distributed(q_lat, q_rope, ckv_new, krope_new, ref,
                   P(ctx.bspec, None, None),
                   c_spec, c_spec, bspec, P()),
         out_specs=(P(ctx.bspec, None, None, None), c_spec, c_spec),
-        check_rep=False,
+        check_vma=False,
     )(q_lat, q_rope, ckv_new, krope_new, ref.stack["ckv"],
       ref.stack["krope"], positions, jnp.asarray(ref.idx, jnp.int32))
     return out, ref.with_stack({"ckv": nckv, "krope": nkrope})

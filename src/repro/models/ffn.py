@@ -56,7 +56,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.kernels.gmm.ops import expert_ffn
@@ -195,7 +194,9 @@ def _moe_alltoall_local(x, params, cfg: ModelConfig, ep_axis: str,
     flat_eid = recv_eid.reshape(ep_size * cap_s)
     valid = flat_eid >= 0
     cap_e = max(int(ep_size * cap_s / E_local * e.capacity_factor), 4)
-    pack2 = fused_route_pack(flat_tok, jnp.where(valid, flat_eid, 0),
+    # empty receive rows go to the no-slot destination E_local: parked on
+    # a real expert they would use up its capacity and drop its tokens
+    pack2 = fused_route_pack(flat_tok, jnp.where(valid, flat_eid, E_local),
                              valid=valid, n_dest=E_local, capacity=cap_e)
     buckets = pack2.buckets
     rank2, keep2 = pack2.rank, pack2.keep
@@ -337,7 +338,9 @@ def _moe_gather_local(x, params, cfg: ModelConfig, ep_axes,
             cap = max(int(N / E * e.capacity_factor
                           * (1 if replicated_experts else 4)), 4)
             ffn_params = params
-        pack = fused_route_pack(xf, jnp.where(mine, my_eid, 0),
+        # assignments owned by other EP ranks take no slot here (dest
+        # n_slots), so they cannot use up a local expert's capacity
+        pack = fused_route_pack(xf, jnp.where(mine, my_eid, n_slots),
                                 valid=mine, k=k, n_dest=n_slots,
                                 capacity=cap)
         rank, keep = pack.rank, pack.keep
@@ -461,18 +464,18 @@ def moe_apply(
         def body_with_placement(x, w, p):
             return gather_body(x, w, placement=p)
 
-        y, (lb, z, counts) = shard_map(
+        y, (lb, z, counts) = jax.shard_map(
             body_with_placement, mesh=mesh,
             in_specs=(x_spec, w_spec, (P(), P(), P())),
             out_specs=(x_spec, (P(), P(), P())),
-            check_rep=False,
+            check_vma=False,
         )(x, routed, pl)
     else:
-        y, (lb, z, counts) = shard_map(
+        y, (lb, z, counts) = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_spec, w_spec),
             out_specs=(x_spec, (P(), P(), P())),
-            check_rep=False,
+            check_vma=False,
         )(x, routed)
 
     if "shared" in params:

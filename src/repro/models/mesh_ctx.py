@@ -10,7 +10,7 @@ from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 @dataclass(frozen=True)
@@ -72,8 +72,17 @@ class MeshCtx:
         return NamedSharding(self.mesh, P())
 
 
+def make_auto_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the model code places
+    arrays with ``NamedSharding``/``shard_map`` and lets the partitioner
+    propagate the rest, so shardings stay out of the array types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_smoke_ctx(**kw) -> MeshCtx:
-    """1×1 mesh on the single CPU device — same code paths, no sharding."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    """1×1 mesh on the first device — same code paths, no sharding."""
+    mesh = make_auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
     kw.setdefault("remat", "none")
     return MeshCtx(mesh=mesh, batch_axes=("data",), **kw)

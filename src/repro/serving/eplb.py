@@ -47,6 +47,7 @@ import collections
 import dataclasses
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 
@@ -327,15 +328,10 @@ class PlacementTable:
         return sorted({int(r) for r in self.rank_of_slot(slots, ep_size)})
 
 
-try:  # register as pytree when jax is importable (pure-numpy use works too)
-    import jax as _jax
-
-    _jax.tree_util.register_pytree_node(
-        PlacementTable,
-        lambda t: t.tree_flatten(),
-        PlacementTable.tree_unflatten)
-except Exception:                                    # pragma: no cover
-    pass
+jax.tree_util.register_pytree_node(
+    PlacementTable,
+    lambda t: t.tree_flatten(),
+    PlacementTable.tree_unflatten)
 
 
 def identity_placement(n_layers: int, n_experts: int,

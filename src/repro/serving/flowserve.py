@@ -37,13 +37,14 @@ class FlowServeEngine:
         if backend_factory is None:
             import jax
 
+            from repro.launch.sharding import init_params
             from repro.models.mesh_ctx import make_smoke_ctx
             from repro.models.transformer import build_model
 
             self.ctx = ctx or make_smoke_ctx()
             self.model = build_model(cfg, self.ctx)
             if params is None:
-                params = self.model.init(jax.random.PRNGKey(seed))
+                params = init_params(self.model, jax.random.PRNGKey(seed))
             self.params = params
             model = self.model
 

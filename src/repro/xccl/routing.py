@@ -40,7 +40,6 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.route_pack.ops import fused_route_pack, placement_route
@@ -138,7 +137,8 @@ def dispatch_local(x_assign, flat_idx, *, ep_axis: str, ep_size: int,
     flat_eid = recv_eid.reshape(-1)
     valid = flat_eid >= 0
     cap_e = max(int(flat.shape[0] / e_local * capacity_factor), 4)
-    pack2 = fused_route_pack(flat, jnp.where(valid, flat_eid, 0),
+    # empty receive rows take no slot (dest e_local)
+    pack2 = fused_route_pack(flat, jnp.where(valid, flat_eid, e_local),
                              valid=valid, n_dest=e_local, capacity=cap_e)
     buckets, rank2, keep2 = pack2.buckets, pack2.rank, pack2.keep
     state = (flat_eid, rank2, keep2, dest_rank, rank1, keep1, cap_s, cap_e)
@@ -206,14 +206,14 @@ def make_a2e_e2a(mesh: Mesh, role_axis: str, n_attn: int, n_expert: int):
     """shard_map-wrapped A2E/E2A over a 1-axis mesh of n_expert ranks."""
     spec = P(role_axis, None, None, None)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     def a2e(x):
         return a2e_local(x[0], role_axis=role_axis, n_attn=n_attn,
                          n_expert=n_expert)[None]
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
-                       out_specs=spec, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     def e2a(x):
         return e2a_local(x[0], role_axis=role_axis, n_attn=n_attn,
                          n_expert=n_expert)[None]

@@ -230,6 +230,60 @@ def test_route_pack(T, d, k, E, C, quantize):
                                       err_msg=name)
 
 
+def test_route_pack_no_slot_destination():
+    """``dest == n_dest`` takes no slot: the other assignments pack
+    exactly as if those rows were absent (kernel and oracle alike)."""
+    from repro.kernels.route_pack.ops import fused_route_pack
+    from repro.kernels.route_pack.ref import route_pack_ref
+    T, d, k, E, C = 40, 16, 2, 4, 6
+    x = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
+    dest = np.asarray(rng.integers(0, E, T * k), np.int32)
+    away = rng.random(T * k) < 0.5
+    dest[away] = E
+    got = fused_route_pack(x, jnp.asarray(dest), jnp.asarray(~away), k=k,
+                           n_dest=E, capacity=C, use_pallas=True,
+                           interpret=True)
+    ref = route_pack_ref(x, jnp.asarray(dest), jnp.asarray(~away), k=k,
+                         n_dest=E, capacity=C)
+    for name in ("buckets", "rank", "keep"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(ref, name)),
+                                      err_msg=name)
+    # the kept rows' ranks equal a pack of the remaining rows alone
+    here = np.nonzero(~away)[0]
+    alone = route_pack_ref(jnp.asarray(np.asarray(x)[here // k]),
+                           jnp.asarray(dest[here]), n_dest=E, capacity=C)
+    np.testing.assert_array_equal(np.asarray(got.rank)[here],
+                                  np.asarray(alone.rank))
+    np.testing.assert_array_equal(np.asarray(got.buckets),
+                                  np.asarray(alone.buckets))
+    assert not np.asarray(got.keep)[away].any()
+
+
+def test_route_pack_tpu_interpreter(monkeypatch):
+    """The row-DMA protocol (one DMA started per kept assignment, one
+    semaphore wait per started DMA, counts carried across blocks) under
+    the TPU-semantics interpreter with race detection. The interpreter
+    takes tens of ms per row, so the block shrinks to 128 rows to cover
+    three blocks."""
+    from jax.experimental.pallas import tpu as pltpu
+    import repro.kernels.route_pack.ops as rp_ops
+    from repro.kernels.route_pack.ref import route_pack_ref
+    monkeypatch.setattr(rp_ops, "_BLOCK", 128)
+    T, d, k, E, C = 50, 128, 6, 8, 24             # 300 rows
+    x = jnp.asarray(rng.standard_normal((T, d)), jnp.bfloat16)
+    dest = jnp.asarray(rng.integers(0, E, T * k), jnp.int32)
+    valid = jnp.asarray(rng.random(T * k) > 0.2)
+    got = rp_ops.fused_route_pack(
+        x, dest, valid, k=k, n_dest=E, capacity=C, use_pallas=True,
+        interpret=pltpu.InterpretParams(detect_races=True))
+    ref = route_pack_ref(x, dest, valid, k=k, n_dest=E, capacity=C)
+    for name in ("buckets", "rank", "keep"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, name), np.float32),
+            np.asarray(getattr(ref, name), np.float32), err_msg=name)
+
+
 def test_route_pack_bf16_payload():
     from repro.kernels.route_pack.ops import fused_route_pack
     from repro.kernels.route_pack.ref import route_pack_ref

@@ -11,6 +11,7 @@ import pytest
 pytestmark = pytest.mark.slow  # compile-heavy: see tests/README.md
 
 _ENV = dict(os.environ,
+            JAX_PLATFORMS="cpu",
             XLA_FLAGS="--xla_force_host_platform_device_count=8",
             PYTHONPATH="src")
 
@@ -29,11 +30,11 @@ def test_dispatch_combine_and_a2e_8dev():
         import jax, jax.numpy as jnp, numpy as np
         import functools
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.models.mesh_ctx import make_auto_mesh
         from repro.xccl.routing import (dispatch_local, combine_local,
                                         make_a2e_e2a)
         assert jax.device_count() == 8, jax.device_count()
-        mesh = jax.make_mesh((8,), ("ep",))
+        mesh = make_auto_mesh((8,), ("ep",))
 
         # ---- dispatch/combine round trip (§3.2) -------------------------
         E, d, n_loc = 16, 32, 24
@@ -48,9 +49,9 @@ def test_dispatch_combine_and_a2e_8dev():
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.standard_normal((8, n_loc, d)), jnp.float32)
         idx = jnp.asarray(rng.integers(0, E, (8, n_loc)), jnp.int32)
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("ep", None, None), P("ep", None)),
-                      out_specs=P("ep", None, None), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P("ep", None, None), P("ep", None)),
+                          out_specs=P("ep", None, None), check_vma=False)
         y = f(x, idx)
         np.testing.assert_allclose(np.asarray(y), np.asarray(x),
                                    rtol=1e-5, atol=1e-5)
@@ -64,9 +65,9 @@ def test_dispatch_combine_and_a2e_8dev():
             y = combine_local(buckets, state, ep_axis="ep", ep_size=8,
                               quantize=True)
             return y[None]
-        fq = shard_map(body_q, mesh=mesh,
-                       in_specs=(P("ep", None, None), P("ep", None)),
-                       out_specs=P("ep", None, None), check_rep=False)
+        fq = jax.shard_map(body_q, mesh=mesh,
+                           in_specs=(P("ep", None, None), P("ep", None)),
+                           out_specs=P("ep", None, None), check_vma=False)
         yq = fq(x, idx)
         err = float(jnp.max(jnp.abs(yq - x)))
         assert err < 0.05, err
@@ -106,11 +107,12 @@ def test_sharded_model_step_8dev():
     out = run_prog("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
-        from repro.models.mesh_ctx import MeshCtx, make_smoke_ctx
+        from repro.models.mesh_ctx import (MeshCtx, make_auto_mesh,
+                                           make_smoke_ctx)
         from repro.models.transformer import build_model
         assert jax.device_count() == 8
         cfg = get_config("deepseek-moe-16b-smoke")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_auto_mesh((4, 2), ("data", "model"))
         ctx = MeshCtx(mesh=mesh, batch_axes=("data",), remat="none")
         m = build_model(cfg, ctx)
         params = m.init(jax.random.PRNGKey(0))
@@ -146,7 +148,8 @@ def test_sharded_ep_placement_decode_8dev():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import ModelConfig, MoEConfig
         from repro.models.ffn import moe_apply, moe_init
-        from repro.models.mesh_ctx import MeshCtx, make_smoke_ctx
+        from repro.models.mesh_ctx import (MeshCtx, make_auto_mesh,
+                                           make_smoke_ctx)
         from repro.serving.eplb import (build_expert_map,
                                         build_placement_table,
                                         identity_placement)
@@ -159,7 +162,7 @@ def test_sharded_ep_placement_decode_8dev():
                                         expert_d_ff=16,
                                         capacity_factor=8.0))
         E = cfg.moe.num_experts
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
         ctx = MeshCtx(mesh=mesh, batch_axes=("data",), remat="none")
         params = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 4, cfg.d_model),
@@ -204,12 +207,12 @@ def test_distributed_decode_attention_8dev():
     out = run_prog("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
-        from repro.models.mesh_ctx import MeshCtx
+        from repro.models.mesh_ctx import MeshCtx, make_auto_mesh
         from repro.models.attention import decode_attention_distributed
         from repro.models.cache_ref import CacheRef
         from repro.kernels.decode_attention.ref import decode_attention_ref
         assert jax.device_count() == 8
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_auto_mesh((1, 8), ("data", "model"))
         ctx = MeshCtx(mesh=mesh, batch_axes=("data",), remat="none")
         rng = np.random.default_rng(0)
         B, H, KV, hd, L = 2, 8, 4, 32, 64
@@ -233,3 +236,43 @@ def test_distributed_decode_attention_8dev():
         print("distributed decode attention OK")
     """)
     assert "distributed decode attention OK" in out
+
+
+def test_sharded_ep_capacity_not_crowded_8dev():
+    """Under sharded EP, assignments a rank does not own (decode gather)
+    and empty receive rows (prefill all-to-all) must take no expert
+    capacity. With a capacity that holds every token, the sharded MoE
+    must equal a dropless one-device reference in both modes."""
+    out = run_prog("""
+        import dataclasses
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs.base import ModelConfig, MoEConfig
+        from repro.models.ffn import moe_apply, moe_init
+        from repro.models.mesh_ctx import (MeshCtx, make_auto_mesh,
+                                           make_smoke_ctx)
+        assert jax.device_count() == 8
+        # 64 experts, top-6: per-expert capacity N/E*cf*4 >= T from
+        # cf >= E/(4k); cf 3 keeps every real assignment, while the ~3/4
+        # of rows other ranks own would overflow a local expert
+        cfg = ModelConfig(name="tiny-moe", d_model=16, d_ff=32,
+                          num_layers=2, num_heads=2, vocab_size=64,
+                          moe=MoEConfig(num_experts=64, top_k=6,
+                                        expert_d_ff=16,
+                                        capacity_factor=3.0))
+        ref_cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=11.0))
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
+        ctx = MeshCtx(mesh=mesh, batch_axes=("data",), remat="none")
+        params = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
+        for mode, shape in (("decode", (8, 1)), ("prefill", (2, 16))):
+            x = jax.random.normal(jax.random.PRNGKey(1),
+                                  shape + (cfg.d_model,), jnp.float32)
+            ys, _ = moe_apply(params, x, cfg=cfg, ctx=ctx, mode=mode)
+            yr, _ = moe_apply(params, x, cfg=ref_cfg, ctx=make_smoke_ctx(),
+                              mode=mode)
+            np.testing.assert_allclose(np.asarray(ys), np.asarray(yr),
+                                       rtol=1e-5, atol=1e-5)
+            print("uncrowded", mode, "OK")
+    """)
+    assert "uncrowded decode OK" in out
+    assert "uncrowded prefill OK" in out
