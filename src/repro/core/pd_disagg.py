@@ -29,6 +29,7 @@ prefill (``supports_chunked_prefill == False``) keep the bulk path.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -211,6 +212,7 @@ class DisaggregatedPD:
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
+        req.t_submit = time.perf_counter()
         if req.prompt_tokens is None:
             req.prompt_tokens = self.tokenizer.encode(req.prompt)
         # context-clip up front so chunk boundaries see the final prompt

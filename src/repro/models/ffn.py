@@ -276,8 +276,9 @@ def _moe_gather_local(x, params, cfg: ModelConfig, ep_axes,
         E_local = E if replicated_experts else E // ep_size
 
         xf = x.reshape(T, d)
-        idx, w, probs, logits = _route(xf, params["router"], k)
-        lb, z, counts = _aux_stats(probs, idx, E, logits)
+        with jax.named_scope("moe_routing"):
+            idx, w, probs, logits = _route(xf, params["router"], k)
+            lb, z, counts = _aux_stats(probs, idx, E, logits)
 
         N = T * k
         flat_idx = idx.reshape(N)
@@ -340,19 +341,22 @@ def _moe_gather_local(x, params, cfg: ModelConfig, ep_axes,
             ffn_params = params
         # assignments owned by other EP ranks take no slot here (dest
         # n_slots), so they cannot use up a local expert's capacity
-        pack = fused_route_pack(xf, jnp.where(mine, my_eid, n_slots),
-                                valid=mine, k=k, n_dest=n_slots,
-                                capacity=cap)
+        with jax.named_scope("moe_dispatch"):
+            pack = fused_route_pack(xf, jnp.where(mine, my_eid, n_slots),
+                                    valid=mine, k=k, n_dest=n_slots,
+                                    capacity=cap)
         rank, keep = pack.rank, pack.keep
-        out_b = _expert_ffn(ffn_params, pack.buckets, owner=owner_arg,
-                            use_pallas=False if train else None)
-        y_assign = out_b[jnp.where(mine, my_eid, 0),
-                         jnp.clip(rank, 0, cap - 1)]
-        y_assign = jnp.where(keep[:, None], y_assign, 0.0)
-        y = jnp.zeros((T, d), jnp.float32).at[tok_of].add(
-            y_assign.astype(jnp.float32) * flat_w[:, None])
-        if not replicated_experts:
-            y = jax.lax.psum(y, ep_axes)        # combine (E2A analogue)
+        with jax.named_scope("moe_experts"):
+            out_b = _expert_ffn(ffn_params, pack.buckets, owner=owner_arg,
+                                use_pallas=False if train else None)
+        with jax.named_scope("moe_combine"):
+            y_assign = out_b[jnp.where(mine, my_eid, 0),
+                             jnp.clip(rank, 0, cap - 1)]
+            y_assign = jnp.where(keep[:, None], y_assign, 0.0)
+            y = jnp.zeros((T, d), jnp.float32).at[tok_of].add(
+                y_assign.astype(jnp.float32) * flat_w[:, None])
+            if not replicated_experts:
+                y = jax.lax.psum(y, ep_axes)    # combine (E2A analogue)
         if overlap:
             # E2A slice-back: keep only this rank's batch shard
             ro = jnp.int32(0)
@@ -479,7 +483,8 @@ def moe_apply(
         )(x, routed)
 
     if "shared" in params:
-        y = y + mlp_apply(params["shared"], x)
+        with jax.named_scope("moe_shared"):
+            y = y + mlp_apply(params["shared"], x)
 
     aux = {
         "moe_lb_loss": lb * e.router_aux_coef,

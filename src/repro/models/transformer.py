@@ -85,6 +85,10 @@ def block_cache_spec(cfg: ModelConfig, kind, batch: int, max_len: int,
     raise ValueError(mixer)
 
 
+#: mixers named ``attention`` in the device programs' op metadata
+_ATTENTION = (ATTN, LOCAL_ATTN, CROSS_ATTN, MLA_ATTN)
+
+
 def block_apply(params, x, *, cfg: ModelConfig, ctx: MeshCtx, kind,
                 mode: str, cache=None, positions=None, memory=None,
                 window_override: int = 0, placement=None):
@@ -94,39 +98,41 @@ def block_apply(params, x, *, cfg: ModelConfig, ctx: MeshCtx, kind,
     phys_owner)`` from a :class:`~repro.serving.eplb.PlacementTable`
     (decode path; ``None`` ⇒ logical expert routing)."""
     mixer, ffn = kind
-    h = rms_norm(x, params["mixer_norm"], cfg.norm_eps)
-    if mixer == ATTN:
-        y, new_cache = A.attn_apply(params["mixer"], h, cfg=cfg, ctx=ctx,
-                                    mode=mode, window=window_override,
-                                    cache=cache, positions=positions)
-    elif mixer == LOCAL_ATTN:
-        w = cfg.sliding_window or cfg.rglru.window
-        y, new_cache = A.attn_apply(params["mixer"], h, cfg=cfg, ctx=ctx,
-                                    mode=mode, window=w, cache=cache,
-                                    positions=positions)
-    elif mixer == CROSS_ATTN:
-        y, new_cache = A.cross_attn_apply(params["mixer"], h, cfg=cfg,
-                                          ctx=ctx, mode=mode, memory=memory,
-                                          cache=cache)
-    elif mixer == MLA_ATTN:
-        y, new_cache = A.mla_apply(params["mixer"], h, cfg=cfg, ctx=ctx,
-                                   mode=mode, cache=cache,
-                                   positions=positions)
-    elif mixer == RGLRU:
-        y, new_cache = R.rglru_apply(params["mixer"], h, cfg=cfg, ctx=ctx,
-                                     mode=mode, cache=cache)
-    elif mixer == SSM:
-        y, new_cache = S.ssm_apply(params["mixer"], h, cfg=cfg, ctx=ctx,
-                                   mode=mode, cache=cache)
-    else:
-        raise ValueError(mixer)
+    with jax.named_scope("attention" if mixer in _ATTENTION else mixer):
+        h = rms_norm(x, params["mixer_norm"], cfg.norm_eps)
+        if mixer == ATTN:
+            y, new_cache = A.attn_apply(
+                params["mixer"], h, cfg=cfg, ctx=ctx, mode=mode,
+                window=window_override, cache=cache, positions=positions)
+        elif mixer == LOCAL_ATTN:
+            w = cfg.sliding_window or cfg.rglru.window
+            y, new_cache = A.attn_apply(
+                params["mixer"], h, cfg=cfg, ctx=ctx, mode=mode, window=w,
+                cache=cache, positions=positions)
+        elif mixer == CROSS_ATTN:
+            y, new_cache = A.cross_attn_apply(
+                params["mixer"], h, cfg=cfg, ctx=ctx, mode=mode,
+                memory=memory, cache=cache)
+        elif mixer == MLA_ATTN:
+            y, new_cache = A.mla_apply(params["mixer"], h, cfg=cfg,
+                                       ctx=ctx, mode=mode, cache=cache,
+                                       positions=positions)
+        elif mixer == RGLRU:
+            y, new_cache = R.rglru_apply(params["mixer"], h, cfg=cfg,
+                                         ctx=ctx, mode=mode, cache=cache)
+        elif mixer == SSM:
+            y, new_cache = S.ssm_apply(params["mixer"], h, cfg=cfg,
+                                       ctx=ctx, mode=mode, cache=cache)
+        else:
+            raise ValueError(mixer)
     x = x + y
 
     counts = jnp.zeros((cfg.moe.num_experts or 1,), jnp.float32)
     aux = jnp.zeros((2,), jnp.float32)
     if ffn == MLP:
-        h = rms_norm(x, params["ffn_norm"], cfg.norm_eps)
-        x = x + F.mlp_apply(params["ffn"], h)
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, params["ffn_norm"], cfg.norm_eps)
+            x = x + F.mlp_apply(params["ffn"], h)
     elif ffn == MOE:
         h = rms_norm(x, params["ffn_norm"], cfg.norm_eps)
         y, moe_aux = F.moe_apply(params["ffn"], h, cfg=cfg, ctx=ctx,
@@ -496,9 +502,10 @@ class Model:
         x, caches, _, _ = self._apply_stack(params, x, mode="chunk",
                                             caches=cache,
                                             positions=offset)
-        h = x[jnp.arange(x.shape[0]), last_pos]
-        logits = jnp.einsum("bd,dv->bv", h.astype(jnp.float32),
-                            self._unembed(params).astype(jnp.float32))
+        with jax.named_scope("output_head"):
+            h = x[jnp.arange(x.shape[0]), last_pos]
+            logits = jnp.einsum("bd,dv->bv", h.astype(jnp.float32),
+                                self._unembed(params).astype(jnp.float32))
         return logits, caches
 
     def decode_step(self, params, cache, tokens, positions, memory=None,
@@ -525,8 +532,9 @@ class Model:
                                                 positions=positions,
                                                 memory=memory,
                                                 placement=placement)
-        logits = jnp.einsum("bd,dv->bv", x[:, -1].astype(jnp.float32),
-                            self._unembed(params).astype(jnp.float32))
+        with jax.named_scope("output_head"):
+            logits = jnp.einsum("bd,dv->bv", x[:, -1].astype(jnp.float32),
+                                self._unembed(params).astype(jnp.float32))
         return logits, x[:, -1:], new_caches
 
     # ------------------------------------------------------------------

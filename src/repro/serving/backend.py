@@ -194,6 +194,8 @@ from typing import Any, List, Optional, Tuple
 
 import numpy as np
 
+from repro.serving.spans import span
+
 PyTree = Any
 
 
@@ -399,12 +401,13 @@ class JAXBackend(ExecutionBackend):
             logits, new_cache = model.decode_step(params, cache, tokens,
                                                   positions,
                                                   placement=placement)
-            if stochastic:
-                key = jax.random.fold_in(base_key, step)
-                toks = sample_tokens(logits, temperatures, key,
-                                     top_k=self.top_k)
-            else:
-                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sampling"):
+                if stochastic:
+                    key = jax.random.fold_in(base_key, step)
+                    toks = sample_tokens(logits, temperatures, key,
+                                         top_k=self.top_k)
+                else:
+                    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return toks, new_cache
 
         # donated fast path (in-place KV) + undonated safe path (the §6.2
@@ -552,23 +555,26 @@ class JAXBackend(ExecutionBackend):
 
         from repro.serving.tokenizer import PAD
 
-        Lc = min(_bucket_len(max(total_len, 1)), self.max_len)
-        if cache is None:
-            if offset != 0:
-                raise ValueError("first chunk must start at offset 0")
-            cache = self.model.init_cache(1, Lc)
-        n = len(tokens)
-        # pad the chunk to its bucket, clamped so the buffer write stays
-        # inside the buffer (padded tail rows hold junk that the next
-        # chunk overwrites / decode masks — exactly like monolithic
-        # prefill's padded tail)
-        Sc = min(_bucket_len(max(n, 1)), Lc - offset)
-        padded = list(tokens) + [PAD] * (Sc - n)
-        arr = jnp.asarray(padded, jnp.int32)[None]
-        logits, cache = self._prefill_chunk(
-            self.params, cache, arr, jnp.int32(offset),
-            jnp.asarray([n - 1], jnp.int32))
-        return cache, np.asarray(logits[0], np.float32)
+        with span("backend.prefill.dispatch"):
+            Lc = min(_bucket_len(max(total_len, 1)), self.max_len)
+            if cache is None:
+                if offset != 0:
+                    raise ValueError("first chunk must start at offset 0")
+                cache = self.model.init_cache(1, Lc)
+            n = len(tokens)
+            # pad the chunk to its bucket, clamped so the buffer write
+            # stays inside the buffer (padded tail rows hold junk that the
+            # next chunk overwrites / decode masks — exactly like
+            # monolithic prefill's padded tail)
+            Sc = min(_bucket_len(max(n, 1)), Lc - offset)
+            padded = list(tokens) + [PAD] * (Sc - n)
+            arr = jnp.asarray(padded, jnp.int32)[None]
+            logits, cache = self._prefill_chunk(
+                self.params, cache, arr, jnp.int32(offset),
+                jnp.asarray([n - 1], jnp.int32))
+        with span("backend.prefill.fetch"):
+            logits = np.asarray(logits[0], np.float32)
+        return cache, logits
 
     @property
     def supports_prefix_kv(self) -> bool:
@@ -627,7 +633,8 @@ class JAXBackend(ExecutionBackend):
             starts[ax] = slot
             return jax.lax.dynamic_update_slice(
                 full, one_leaf.astype(full.dtype), tuple(starts))
-        return jax.tree_util.tree_map_with_path(one, cache, cache1)
+        with jax.named_scope("write_slot"):
+            return jax.tree_util.tree_map_with_path(one, cache, cache1)
 
     def write_slot(self, cache: PyTree, cache1: PyTree,
                    slot: int) -> PyTree:
@@ -667,13 +674,15 @@ class JAXBackend(ExecutionBackend):
                       ) -> Tuple[Any, PyTree]:
         import jax.numpy as jnp
 
-        stochastic = bool(np.any(np.asarray(temperatures) > 0.0))
-        fn = self._decode_sample if donate else self._decode_sample_safe
-        toks, new_cache = fn(self.params, cache, jnp.asarray(tokens),
-                             jnp.asarray(positions),
-                             jnp.asarray(temperatures, jnp.float32),
-                             self._base_key, jnp.int32(step),
-                             self._placement, stochastic=stochastic)
+        with span("backend.decode.dispatch"):
+            stochastic = bool(np.any(np.asarray(temperatures) > 0.0))
+            fn = (self._decode_sample if donate
+                  else self._decode_sample_safe)
+            toks, new_cache = fn(self.params, cache, jnp.asarray(tokens),
+                                 jnp.asarray(positions),
+                                 jnp.asarray(temperatures, jnp.float32),
+                                 self._base_key, jnp.int32(step),
+                                 self._placement, stochastic=stochastic)
         return toks, new_cache
 
     def init_mtp_cache(self, max_batch: int, max_len: int) -> PyTree:
@@ -693,12 +702,13 @@ class JAXBackend(ExecutionBackend):
 
         if not self.mtp_k:
             raise NotImplementedError("backend built with mtp_k=0")
-        stochastic = bool(np.any(np.asarray(temperatures) > 0.0))
-        fn = (self._decode_sample_mtp if donate
-              else self._decode_sample_mtp_safe)
-        block, n_acc, new_cache, new_mtp = fn(
-            self.params, cache, mtp_cache, jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(temperatures, jnp.float32), self._base_key,
-            jnp.int32(step), self._placement, stochastic=stochastic)
+        with span("backend.decode.dispatch"):
+            stochastic = bool(np.any(np.asarray(temperatures) > 0.0))
+            fn = (self._decode_sample_mtp if donate
+                  else self._decode_sample_mtp_safe)
+            block, n_acc, new_cache, new_mtp = fn(
+                self.params, cache, mtp_cache, jnp.asarray(tokens),
+                jnp.asarray(positions),
+                jnp.asarray(temperatures, jnp.float32), self._base_key,
+                jnp.int32(step), self._placement, stochastic=stochastic)
         return block, n_acc, new_cache, new_mtp

@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -42,6 +43,7 @@ from repro.serving.kv_cache import (BlockAllocator, PodKVDirectory,
                                     RadixTree, RemotePin)
 from repro.serving.request import Request, RequestState
 from repro.serving.scheduler import DPStatus
+from repro.serving.spans import span
 from repro.serving.tokenizer import EOS, PAD, ByteTokenizer
 
 PyTree = Any
@@ -153,10 +155,11 @@ class DPGroup:
         backend support the tree is accounting-only (hit statistics for
         TE routing)."""
         if self._prefix_kv:
-            self.prefix_cache.insert(
-                toks,
-                lambda s, e: self.backend.slice_prefill_kv(
-                    cache, toks, s, e))
+            with span("dp.prefill.insert") as sp:
+                sp.n = self.prefix_cache.insert(
+                    toks,
+                    lambda s, e: self.backend.slice_prefill_kv(
+                        cache, toks, s, e))
         else:
             self.prefix_cache.insert(toks)
 
@@ -228,67 +231,77 @@ class DPGroup:
         are still outstanding or when this chunk was skipped entirely
         off a cache hit."""
         req = work.req
-        toks = req.prompt_tokens
-        # context clipping mirrors run_prefill — engines clip at submit,
-        # this is the safety net for direct callers
-        limit = max(self.max_len - req.max_new_tokens - 1, 16)
-        if len(toks) > limit and work.is_first:
-            toks = toks[-limit:]
-            req.prompt_tokens = toks
-            req.prefill_pos = min(req.prefill_pos, len(toks))
-        start = work.start
-        if work.is_first:
-            self._drop_chunk_state(req)
-            if self._prefix_kv:
-                m = self.prefix_cache.match_blocks(toks)
-                local = m.n_tokens if (m.n_blocks > 0
-                                       and m.has_payloads) else 0
-                pin = self._acquire_remote(toks, local)
-                if pin is not None:
-                    # pod-pooled remote hit: UB-read the owner's blocks
-                    # and seed from them; the pin stays held (owner path
-                    # eviction-proof) until the prefill completes or is
-                    # dropped — both release through _unlock_chunk
-                    self._chunk_pins[req.req_id] = pin
-                    payloads = self.backend.read_remote_kv(pin.payloads)
-                    self._chunk_caches[req.req_id] = \
-                        self.backend.seed_prefill_cache(
-                            payloads, pin.n_tokens, len(toks))
-                    req.prefix_hit_tokens = pin.n_tokens
-                    self.allocator.extend(req.req_id, pin.n_tokens)
-                    if pin.n_tokens >= work.end:
-                        req.prefill_pos = max(req.prefill_pos,
-                                              pin.n_tokens)
-                        return None
-                    start = pin.n_tokens
-                elif local > 0:
-                    self.prefix_cache.lock(m.nodes)
-                    self._chunk_locks[req.req_id] = m.nodes
-                    self._chunk_caches[req.req_id] = \
-                        self.backend.seed_prefill_cache(
-                            m.payloads, m.n_tokens, len(toks))
-                    req.prefix_hit_tokens = m.n_tokens
-                    self.allocator.extend(req.req_id, m.n_tokens)
-                    if m.n_tokens >= work.end:
-                        # whole chunk cached: skip execution, jump the
-                        # cursor past every fully-cached chunk
-                        req.prefill_pos = max(req.prefill_pos,
-                                              m.n_tokens)
-                        return None
-                    start = m.n_tokens    # run only the chunk's suffix
-        end = min(work.end, len(toks))
-        chunk = toks[start:end]
-        self.allocator.extend(req.req_id, end)
-        cache, logits = self.backend.prefill_chunk(
-            self._chunk_caches.pop(req.req_id, None), chunk, start,
-            len(toks))
-        if work.end >= len(toks):             # prompt complete
-            logits = np.asarray(logits, np.float32)
-            self._unlock_chunk(req)
-            self._cache_insert(toks, cache)
-            return cache, logits
-        self._chunk_caches[req.req_id] = cache
-        return None
+        if req.t_prefill_start is None:
+            req.t_prefill_start = time.perf_counter()
+        with span("dp.prefill", req.req_id) as sp:
+            toks = req.prompt_tokens
+            # context clipping mirrors run_prefill — engines clip at
+            # submit, this is the safety net for direct callers
+            limit = max(self.max_len - req.max_new_tokens - 1, 16)
+            if len(toks) > limit and work.is_first:
+                toks = toks[-limit:]
+                req.prompt_tokens = toks
+                req.prefill_pos = min(req.prefill_pos, len(toks))
+            start = work.start
+            if work.is_first:
+                self._drop_chunk_state(req)
+                if self._prefix_kv:
+                    with span("dp.prefill.match"):
+                        m = self.prefix_cache.match_blocks(toks)
+                        local = m.n_tokens if (m.n_blocks > 0
+                                               and m.has_payloads) else 0
+                        pin = self._acquire_remote(toks, local)
+                    if pin is not None:
+                        # pod-pooled remote hit: UB-read the owner's
+                        # blocks and seed from them; the pin stays held
+                        # (owner path eviction-proof) until the prefill
+                        # completes or is dropped — both release through
+                        # _unlock_chunk
+                        self._chunk_pins[req.req_id] = pin
+                        with span("dp.prefill.seed"):
+                            payloads = self.backend.read_remote_kv(
+                                pin.payloads)
+                            self._chunk_caches[req.req_id] = \
+                                self.backend.seed_prefill_cache(
+                                    payloads, pin.n_tokens, len(toks))
+                        req.prefix_hit_tokens = pin.n_tokens
+                        self.allocator.extend(req.req_id, pin.n_tokens)
+                        if pin.n_tokens >= work.end:
+                            req.prefill_pos = max(req.prefill_pos,
+                                                  pin.n_tokens)
+                            return None
+                        start = pin.n_tokens
+                    elif local > 0:
+                        self.prefix_cache.lock(m.nodes)
+                        self._chunk_locks[req.req_id] = m.nodes
+                        with span("dp.prefill.seed"):
+                            self._chunk_caches[req.req_id] = \
+                                self.backend.seed_prefill_cache(
+                                    m.payloads, m.n_tokens, len(toks))
+                        req.prefix_hit_tokens = m.n_tokens
+                        self.allocator.extend(req.req_id, m.n_tokens)
+                        if m.n_tokens >= work.end:
+                            # whole chunk cached: skip execution, jump
+                            # the cursor past every fully-cached chunk
+                            req.prefill_pos = max(req.prefill_pos,
+                                                  m.n_tokens)
+                            return None
+                        start = m.n_tokens    # run only the suffix
+            end = min(work.end, len(toks))
+            chunk = toks[start:end]
+            sp.n = len(chunk)
+            self.allocator.extend(req.req_id, end)
+            cache, logits = self.backend.prefill_chunk(
+                self._chunk_caches.pop(req.req_id, None), chunk, start,
+                len(toks))
+            if work.end >= len(toks):             # prompt complete
+                logits = np.asarray(logits, np.float32)
+                self._unlock_chunk(req)
+                self._cache_insert(toks, cache)
+                req.t_prefill_done = time.perf_counter()
+                return cache, logits
+            self._chunk_caches[req.req_id] = cache
+            return None
 
     def partial_prefill_cache(self, req: Request) -> Optional[PyTree]:
         """The backend-opaque partial-prefill cache of an in-flight
@@ -366,21 +379,24 @@ class DPGroup:
 
     def admit(self, req: Request, cache1: PyTree,
               last_logits: np.ndarray) -> int:
-        slot_id = next(i for i, s in enumerate(self.slots) if s.free)
-        self.allocator.extend(req.req_id,
-                              req.prompt_len + req.max_new_tokens)
-        self.cache = self.backend.write_slot(self.cache, cache1, slot_id)
-        if self.mtp_k:
-            self.mtp_cache = self.backend.reset_mtp_slot(self.mtp_cache,
-                                                         slot_id)
-        first = self._sample(last_logits, req.temperature)
-        req.n_emitted += 1
-        self._out_q.put((req, int(first)))
-        req.state = RequestState.DECODING
-        req.slot = slot_id
-        req.dp_group = self.dp_id
-        self.slots[slot_id] = Slot(req=req, next_token=int(first),
-                                   position=req.prompt_len)
+        req.t_admit = time.perf_counter()
+        with span("dp.admit", req.req_id):
+            slot_id = next(i for i, s in enumerate(self.slots) if s.free)
+            self.allocator.extend(req.req_id,
+                                  req.prompt_len + req.max_new_tokens)
+            self.cache = self.backend.write_slot(self.cache, cache1,
+                                                 slot_id)
+            if self.mtp_k:
+                self.mtp_cache = self.backend.reset_mtp_slot(
+                    self.mtp_cache, slot_id)
+            first = self._sample(last_logits, req.temperature)
+            req.n_emitted += 1
+            self._out_q.put((req, int(first)))
+            req.state = RequestState.DECODING
+            req.slot = slot_id
+            req.dp_group = self.dp_id
+            self.slots[slot_id] = Slot(req=req, next_token=int(first),
+                                       position=req.prompt_len)
         return slot_id
 
     # ------------------------------------------------------------------
@@ -482,20 +498,24 @@ class DPGroup:
         """
         if self.active == 0 or self._pending is not None:
             return False
-        tokens, positions, temps, active = self._gather_step_inputs()
-        if self.mtp_k:
-            blocks_dev, n_acc_dev, new_cache, new_mtp = \
-                self.backend.decode_sample_mtp(
-                    self.cache, self.mtp_cache, tokens, positions, temps,
-                    self.steps)
+        with span("dp.decode.launch") as sp:
+            with span("dp.decode.inputs"):
+                tokens, positions, temps, active = \
+                    self._gather_step_inputs()
+            sp.n = len(active)
+            if self.mtp_k:
+                blocks_dev, n_acc_dev, new_cache, new_mtp = \
+                    self.backend.decode_sample_mtp(
+                        self.cache, self.mtp_cache, tokens, positions,
+                        temps, self.steps)
+                self.cache = new_cache
+                self.mtp_cache = new_mtp
+                self._pending = ((blocks_dev, n_acc_dev), active)
+                return True
+            toks_dev, new_cache = self.backend.decode_sample(
+                self.cache, tokens, positions, temps, self.steps)
             self.cache = new_cache
-            self.mtp_cache = new_mtp
-            self._pending = ((blocks_dev, n_acc_dev), active)
-            return True
-        toks_dev, new_cache = self.backend.decode_sample(
-            self.cache, tokens, positions, temps, self.steps)
-        self.cache = new_cache
-        self._pending = (toks_dev, active)
+            self._pending = (toks_dev, active)
         return True
 
     def decode_complete(self) -> int:
@@ -503,22 +523,29 @@ class DPGroup:
         with MTP 4·B·(k+1) + 4·B) and run the host-side bookkeeping."""
         if self._pending is None:
             return 0
-        toks_dev, active = self._pending
-        self._pending = None
-        if self.mtp_k:
-            blocks_dev, n_acc_dev = toks_dev
-            produced = self._apply_sampled_mtp(
-                np.asarray(blocks_dev), np.asarray(n_acc_dev), active)
-        else:
-            produced = self._apply_sampled(np.asarray(toks_dev), active)
-        if self._has_pending_placement:
-            # deferred EPLB swap: the donated-cache step has retired, so
-            # the placement can change before the next launch (§4.5
-            # reconfiguration never lands mid-iteration)
-            table = self._pending_placement
-            self._pending_placement = None
-            self._has_pending_placement = False
-            self.backend.apply_placement(table)
+        with span("dp.decode.complete"):
+            toks_dev, active = self._pending
+            self._pending = None
+            if self.mtp_k:
+                with span("dp.decode.fetch"):
+                    blocks = np.asarray(toks_dev[0])
+                    n_acc = np.asarray(toks_dev[1])
+                with span("dp.decode.apply"):
+                    produced = self._apply_sampled_mtp(blocks, n_acc,
+                                                       active)
+            else:
+                with span("dp.decode.fetch"):
+                    toks = np.asarray(toks_dev)
+                with span("dp.decode.apply"):
+                    produced = self._apply_sampled(toks, active)
+            if self._has_pending_placement:
+                # deferred EPLB swap: the donated-cache step has retired,
+                # so the placement can change before the next launch
+                # (§4.5 reconfiguration never lands mid-iteration)
+                table = self._pending_placement
+                self._pending_placement = None
+                self._has_pending_placement = False
+                self.backend.apply_placement(table)
         return produced
 
     # ------------------------------------------------------------------
@@ -585,8 +612,7 @@ class DPGroup:
         s = self.slots[slot_id]
         req = s.req
         self.allocator.free(req.req_id)
-        import time as _t
-        req.t_finished = _t.monotonic()
+        req.t_finished = time.perf_counter()
         req.state = RequestState.FINISHED
         self.finished.append(req)
         self.slots[slot_id] = Slot()
@@ -618,8 +644,7 @@ class DPGroup:
 
     def drain(self) -> None:
         while not self._out_q.empty():
-            import time as _t
-            _t.sleep(0.001)
+            time.sleep(0.001)
 
     def close(self) -> None:
         self._out_q.put(None)

@@ -14,6 +14,7 @@ from repro.configs.base import ModelConfig
 from repro.serving.backend import ExecutionBackend, JAXBackend
 from repro.serving.dp_group import DPGroup
 from repro.serving.request import Request, RequestState
+from repro.serving.spans import span
 from repro.serving.te_shell import TEShell
 from repro.serving.tokenizer import ByteTokenizer
 
@@ -78,6 +79,7 @@ class FlowServeEngine:
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
+        req.t_submit = time.perf_counter()
         if req.prompt_tokens is None:
             req.prompt_tokens = self.tokenizer.encode(req.prompt)
         self.waiting.append(req)
@@ -107,32 +109,35 @@ class FlowServeEngine:
         overlaps the others' host-side dispatch and bookkeeping instead
         of serializing on a per-group ``[B, V]`` logits sync.
         """
-        # feed new submissions to the chunk scheduler (context-clipped
-        # up front so chunk boundaries are computed on the final prompt)
-        for req in self.waiting:
-            limit = max(self.max_len - req.max_new_tokens - 1, 16)
-            if req.prompt_len > limit:
-                req.prompt_tokens = req.prompt_tokens[-limit:]
-            self.shell.submit_prefill(req)
-        self.waiting = []
-        for dp, works in zip(self.dps, self.shell.schedule_prefill_chunks()):
-            for work in works:
-                work.req.state = RequestState.PREFILLING
-                done = dp.run_prefill_chunk(work)
-                if done is not None:
-                    self._ready.append((work.req, dp) + done)
-        still_ready: List[tuple] = []
-        for req, dp, cache1, logits in self._ready:
-            if dp.can_admit(req):
-                dp.admit(req, cache1, logits)
-            else:
-                still_ready.append((req, dp, cache1, logits))
-        self._ready = still_ready
-        for dp in self.dps:
-            dp.decode_launch()
-        produced = 0
-        for dp in self.dps:
-            produced += dp.decode_complete()
+        with span("engine.step"):
+            # feed new submissions to the chunk scheduler (context-clipped
+            # up front so chunk boundaries are computed on the final
+            # prompt)
+            for req in self.waiting:
+                limit = max(self.max_len - req.max_new_tokens - 1, 16)
+                if req.prompt_len > limit:
+                    req.prompt_tokens = req.prompt_tokens[-limit:]
+                self.shell.submit_prefill(req)
+            self.waiting = []
+            for dp, works in zip(self.dps,
+                                 self.shell.schedule_prefill_chunks()):
+                for work in works:
+                    work.req.state = RequestState.PREFILLING
+                    done = dp.run_prefill_chunk(work)
+                    if done is not None:
+                        self._ready.append((work.req, dp) + done)
+            still_ready: List[tuple] = []
+            for req, dp, cache1, logits in self._ready:
+                if dp.can_admit(req):
+                    dp.admit(req, cache1, logits)
+                else:
+                    still_ready.append((req, dp, cache1, logits))
+            self._ready = still_ready
+            for dp in self.dps:
+                dp.decode_launch()
+            produced = 0
+            for dp in self.dps:
+                produced += dp.decode_complete()
         return produced
 
     def run_eplb(self, n_npus: Optional[int] = None,

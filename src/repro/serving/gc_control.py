@@ -16,6 +16,8 @@ import os
 import time
 from typing import Callable, List, Optional
 
+from repro.serving.spans import span
+
 
 class ProactiveGC:
     def __init__(self, every_n_steps: int = 200, enabled: bool = True):
@@ -23,7 +25,6 @@ class ProactiveGC:
         self.enabled = enabled
         self.steps = 0
         self.collections = 0
-        self.gc_time_total = 0.0
         if enabled:
             gc.disable()
 
@@ -36,10 +37,10 @@ class ProactiveGC:
         if self.steps % self.every:
             return None
         t0 = time.monotonic()
-        gc.collect()
+        with span("dp.gc"):
+            gc.collect()
         dt = time.monotonic() - t0
         self.collections += 1
-        self.gc_time_total += dt
         return dt
 
     def close(self) -> None:

@@ -58,7 +58,14 @@ class Request:
     # group (output_tokens is appended by the async output-shortcutting
     # worker, so its length must not drive scheduling decisions)
     n_emitted: int = 0
-    t_arrival: float = dataclasses.field(default_factory=time.monotonic)
+    # host stamps on time.perf_counter (a client's clock): submission,
+    # start of the first prefill chunk (executed, or skipped on a cache
+    # hit), prompt complete, admission to a decode slot, first and last
+    # token
+    t_submit: Optional[float] = None
+    t_prefill_start: Optional[float] = None
+    t_prefill_done: Optional[float] = None
+    t_admit: Optional[float] = None
     t_first_token: Optional[float] = None
     t_finished: Optional[float] = None
     prefill_te: Optional[int] = None
@@ -82,20 +89,20 @@ class Request:
 
     @property
     def ttft(self) -> Optional[float]:
-        if self.t_first_token is None:
+        if self.t_first_token is None or self.t_submit is None:
             return None
-        return self.t_first_token - self.t_arrival
+        return self.t_first_token - self.t_submit
 
     @property
     def tpot(self) -> Optional[float]:
         if self.t_finished is None or len(self.output_tokens) < 2:
             return None
-        return ((self.t_finished - (self.t_first_token or self.t_arrival))
-                / max(len(self.output_tokens) - 1, 1))
+        return ((self.t_finished - self.t_first_token)
+                / (len(self.output_tokens) - 1))
 
     def emit(self, token: int) -> None:
         if self.t_first_token is None:
-            self.t_first_token = time.monotonic()
+            self.t_first_token = time.perf_counter()
         self.output_tokens.append(token)
         if self.on_token is not None:
             self.on_token(token)

@@ -22,6 +22,7 @@ from repro.serving.reliability import (Clock, HeartbeatPeer,
 from repro.serving.request import Request, RequestState
 from repro.serving.scheduler import (ChunkWork, DecodeLoadBalancer,
                                      DPStatus, PrefillScheduler)
+from repro.serving.spans import span
 
 
 class TEShell:
@@ -88,33 +89,36 @@ class TEShell:
         heartbeat has since declared unhealthy are requeued with their
         cursor reset — the partial KV there is lost — and their chunk
         caches released."""
-        statuses = {s.dp_id: s for s in self.statuses()}
-        for idx, d in enumerate(self.dps):
-            if not statuses[d.dp_id].healthy:
-                for req in self.prefill_sched.requeue_dp(idx):
-                    d.drop_partial_prefill(req)
+        with span("shell.schedule"):
+            statuses = {s.dp_id: s for s in self.statuses()}
+            for idx, d in enumerate(self.dps):
+                if not statuses[d.dp_id].healthy:
+                    for req in self.prefill_sched.requeue_dp(idx):
+                        d.drop_partial_prefill(req)
 
-        def can_admit(dp_idx: int, req: Request) -> bool:
-            s = statuses[self.dps[dp_idx].dp_id]
-            return s.healthy and self.dps[dp_idx].can_admit(req)
+            def can_admit(dp_idx: int, req: Request) -> bool:
+                s = statuses[self.dps[dp_idx].dp_id]
+                return s.healthy and self.dps[dp_idx].can_admit(req)
 
-        def hit_rate(req: Request) -> float:
-            # Pod-pooled prefix KV: a prefix cached on ANOTHER TE's DP is
-            # still a hit for admission ordering — the owner's blocks are
-            # UB-readable, so the request skips the same prefill work.
-            # The pod directory's view is a superset of the local one, so
-            # a plain max folds remote coverage in without double count.
-            local = max(d.prefix_cache.match_fraction(req.prompt_tokens)
-                        for d in self.dps)
-            pods = {d.pod_dir for d in self.dps
-                    if getattr(d, "pod_dir", None) is not None}
-            remote = max(
-                (p.match_fraction(req.prompt_tokens) for p in pods),
-                default=0.0)
-            return max(local, remote)
+            def hit_rate(req: Request) -> float:
+                # Pod-pooled prefix KV: a prefix cached on ANOTHER TE's
+                # DP is still a hit for admission ordering — the owner's
+                # blocks are UB-readable, so the request skips the same
+                # prefill work. The pod directory's view is a superset of
+                # the local one, so a plain max folds remote coverage in
+                # without double count.
+                local = max(
+                    d.prefix_cache.match_fraction(req.prompt_tokens)
+                    for d in self.dps)
+                pods = {d.pod_dir for d in self.dps
+                        if getattr(d, "pod_dir", None) is not None}
+                remote = max(
+                    (p.match_fraction(req.prompt_tokens) for p in pods),
+                    default=0.0)
+                return max(local, remote)
 
-        return self.prefill_sched.schedule_step(
-            hit_rate_fn=hit_rate, can_admit_fn=can_admit)
+            return self.prefill_sched.schedule_step(
+                hit_rate_fn=hit_rate, can_admit_fn=can_admit)
 
     # -- responsibility 2: EPLB trigger -------------------------------------
     def record_expert_counts(self, counts: np.ndarray) -> None:
