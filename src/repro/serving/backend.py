@@ -136,11 +136,26 @@ rebuilds a partial-prefill cache whose first ``prefix_len`` positions
 hold those payloads — the handle it returns is what ``prefill_chunk``
 accepts at ``offset == prefix_len``. Together they are the storage/reuse
 half of the radix-tree prefix cache (``serving/kv_cache.py``): on insert
-the :class:`~repro.serving.dp_group.DPGroup` slices one payload per KV
+the :class:`~repro.serving.dp_group.DPGroup` stores one payload per KV
 block, and on a partial hit it seeds a fresh cache from the stored
 blocks so only the un-cached suffix runs through the chunk programs.
 Contract details:
 
+* ``slice_prefill_blocks(cache, tokens, first_block, n_blocks,
+  block_size)`` is the insert's batched slice: the payloads of blocks
+  ``[first_block, first_block + n_blocks)``, block ``b`` covering tokens
+  ``[b * block_size, (b + 1) * block_size)``, each equal to what
+  ``slice_prefill_kv`` gives for that range. The radix insert makes one
+  such call for all of a prompt's new blocks. The base class loops over
+  ``slice_prefill_kv``. :class:`JAXBackend` runs one jitted program per
+  cache shape (``xccl/pd_transfer.slice_kv_blocks``) that cuts every
+  whole block of the buffer and keeps the ones asked for; its
+  ``slice_prefill_kv`` takes one block (``start`` a multiple of the
+  range's width) from the same program, so one warm-up call per cache
+  shape compiles the insert's slicer too.
+* Payloads must be fresh buffers, never views or aliases of ``cache``:
+  the chunk programs donate their cache, and the radix tree holds the
+  payloads past that.
 * ``payloads`` is a list of consecutive block slices (as produced by
   ``slice_prefill_kv``) covering ``[0, prefix_len)`` in order.
 * The seeded cache must make a subsequent
@@ -256,6 +271,17 @@ class ExecutionBackend(abc.ABC):
         model, derive the payload from the token range instead)."""
         raise NotImplementedError(
             f"{type(self).__name__} does not support prefix-KV slicing")
+
+    def slice_prefill_blocks(self, cache: PyTree, tokens: List[int],
+                             first_block: int, n_blocks: int,
+                             block_size: int) -> List[PyTree]:
+        """The payloads of blocks ``[first_block, first_block +
+        n_blocks)`` of ``block_size`` tokens each, in one call — see the
+        prefix-KV contract in the module docstring. Default: one
+        ``slice_prefill_kv`` per block."""
+        return [self.slice_prefill_kv(cache, tokens, b * block_size,
+                                      (b + 1) * block_size)
+                for b in range(first_block, first_block + n_blocks)]
 
     def seed_prefill_cache(self, payloads: List[PyTree], prefix_len: int,
                            total_len: int) -> PyTree:
@@ -584,12 +610,30 @@ class JAXBackend(ExecutionBackend):
 
     def slice_prefill_kv(self, cache: PyTree, tokens: List[int],
                          start: int, end: int) -> PyTree:
-        from repro.xccl.pd_transfer import slice_kv_chunk
+        """The range must be a block, ``start`` a multiple of its width:
+        it comes from the batched slicer's program."""
+        width = end - start
+        if width <= 0 or start % width:
+            raise ValueError(f"[{start}, {end}) is not a block")
+        return self.slice_prefill_blocks(cache, tokens, start // width, 1,
+                                         width)[0]
 
-        # slice_kv_chunk produces fresh arrays — required, since the
-        # chunk programs donate their cache buffer and the radix tree
-        # must hold payloads that outlive it
-        return slice_kv_chunk(cache, start, end)
+    def slice_prefill_blocks(self, cache: PyTree, tokens: List[int],
+                             first_block: int, n_blocks: int,
+                             block_size: int) -> List[PyTree]:
+        """One dispatch of the module-level slicer, which materialises
+        every whole block of the buffer (the span's ``n``); the blocks
+        not asked for are dropped."""
+        from repro.xccl.pd_transfer import slice_kv_blocks
+
+        with span("backend.prefill.slice") as sp:
+            blocks = slice_kv_blocks(cache, block_size)
+            sp.n = len(blocks)
+        if first_block < 0 or first_block + n_blocks > len(blocks):
+            raise ValueError(
+                f"blocks [{first_block}, {first_block + n_blocks}) outside "
+                f"the buffer's {len(blocks)} whole blocks")
+        return blocks[first_block:first_block + n_blocks]
 
     def seed_prefill_cache(self, payloads: List[PyTree], prefix_len: int,
                            total_len: int) -> PyTree:

@@ -151,15 +151,16 @@ class DPGroup:
     # ------------------------------------------------------------------
     def _cache_insert(self, toks: List[int], cache: PyTree) -> None:
         """Store the prompt's full KV blocks in the radix cache. The
-        slicer runs only for blocks not already cached; without prefix-KV
-        backend support the tree is accounting-only (hit statistics for
-        TE routing)."""
+        blocks not already cached are sliced in one backend call; without
+        prefix-KV backend support the tree is accounting-only (hit
+        statistics for TE routing)."""
         if self._prefix_kv:
+            bs = self.prefix_cache.block_size
             with span("dp.prefill.insert") as sp:
                 sp.n = self.prefix_cache.insert(
                     toks,
-                    lambda s, e: self.backend.slice_prefill_kv(
-                        cache, toks, s, e))
+                    lambda first, n: self.backend.slice_prefill_blocks(
+                        cache, toks, first, n, bs))
         else:
             self.prefix_cache.insert(toks)
 

@@ -175,7 +175,7 @@ class RadixTree:
       (a later `_split` of a locked node leaves the new parent
       unreferenced on purpose — lock holders release exactly the node
       objects they locked).
-    - `insert(tokens, payload_fn)` adds the un-cached suffix blocks,
+    - `insert(tokens, payloads_fn)` adds the un-cached suffix blocks,
       allocating from the tree's own allocator (evicting unreferenced
       LRU leaves on pressure) — re-inserting a cached prefix is a no-op,
       and *only* real payload-bearing blocks are ever stored (no
@@ -325,10 +325,11 @@ class RadixTree:
     # -- insertion / eviction -----------------------------------------
 
     def insert(self, tokens: List[int],
-               payload_fn: Optional[Callable[[int, int], PyTree]] = None
-               ) -> int:
-        """Cache `tokens`' full blocks; `payload_fn(start, end)` slices
-        the KV pytree for one block's token range (None for an
+               payloads_fn: Optional[
+                   Callable[[int, int], List[PyTree]]] = None) -> int:
+        """Cache `tokens`' full blocks; `payloads_fn(first_block,
+        n_blocks)` returns the KV pytrees of the new blocks
+        `[first_block, first_block + n_blocks)` in one call (None for an
         accounting-only tree, e.g. the sim's TE prefix directory).
         Returns the number of newly cached blocks."""
         hs = hash_blocks(tokens, self.block_size)
@@ -360,12 +361,10 @@ class RadixTree:
             return 0
         nid = next(self._ids)
         block_ids = self.allocator.allocate(nid, have * self.block_size)
-        bs = self.block_size
-        payloads = [payload_fn(b * bs, (b + 1) * bs)
-                    if payload_fn is not None else None
-                    for b in range(i, i + have)]
-        new = RadixNode(hs[i:i + have], i * bs, node, payloads, block_ids,
-                        nid)
+        payloads = (payloads_fn(i, have) if payloads_fn is not None
+                    else [None] * have)
+        new = RadixNode(hs[i:i + have], i * self.block_size, node,
+                        payloads, block_ids, nid)
         node.children[new.hashes[0]] = new
         self._nodes[nid] = new
         self._tick += 1

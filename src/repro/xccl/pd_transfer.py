@@ -24,10 +24,15 @@ shared latency model of the compute/transfer pipeline — the exposed
 transfer cost of a streamed prefill is essentially the LAST chunk's
 transfer, everything earlier hides under later chunks' compute (the
 overlap P/D-Serve and CloudMatrix-Infer rely on for TTFT tails).
+
+The radix prefix cache stores a prompt's KV block by block:
+:func:`slice_kv_blocks` cuts every whole block of a prefill cache in
+one program.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -98,6 +103,26 @@ def slice_kv_chunk(kv: PyTree, start: int, end: int) -> PyTree:
         idx[ax] = slice(start, end)
         return leaf[tuple(idx)]
     return jax.tree_util.tree_map_with_path(one, kv)
+
+
+@functools.partial(jax.jit, static_argnames="block_size")
+def slice_kv_blocks(kv: PyTree, block_size: int) -> List[PyTree]:
+    """Cut every whole ``block_size``-token block of a prefill cache in
+    one program: entry ``b`` equals ``slice_kv_chunk(kv, b * block_size,
+    (b + 1) * block_size)`` in pytree, shape, dtype and bits. Each block
+    is an output buffer of its own, never an alias of ``kv`` (nothing is
+    donated), so it outlives a cache that a later program donates. One
+    compile per cache shape; module-level, so every backend in the
+    process shares the executables."""
+    paths = jax.tree_util.tree_flatten_with_path(kv)[0]
+    n = min(leaf.shape[_seq_axis(p)] for p, leaf in paths) // block_size
+
+    def block(b):
+        return jax.tree_util.tree_map_with_path(
+            lambda p, leaf: jax.lax.slice_in_dim(
+                leaf, b * block_size, (b + 1) * block_size,
+                axis=_seq_axis(p)), kv)
+    return [block(b) for b in range(n)]
 
 
 def assemble_chunks(chunks: Sequence[PyTree]) -> PyTree:

@@ -23,7 +23,8 @@ import numpy as np
 import pytest
 
 from repro.serving.kv_cache import (BlockAllocator, DoubleFree, OutOfBlocks,
-                                    PrefixCache, RadixTree, hash_blocks)
+                                    PodKVDirectory, PrefixCache, RadixTree,
+                                    hash_blocks)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -222,18 +223,19 @@ def test_no_placeholder_entries_leak_capacity():
     calls = []
     toks = list(range(0, 200))          # 12 full blocks + tail
     t = RadixTree(capacity_blocks=64, block_size=BS)
-    new = t.insert(toks, lambda s, e: calls.append((s, e)) or {"s": s})
+    new = t.insert(toks, lambda b, n: calls.append((b, n))
+                   or [{"b": i} for i in range(b, b + n)])
     assert new == len(toks) // BS == 12
     assert t.n_cached_blocks == 12      # capacity == real payload blocks
     assert len(t) == 1                  # one path-compressed edge
-    assert calls == [(b * BS, (b + 1) * BS) for b in range(12)]
+    assert calls == [(0, 12)]           # one batched call for the blocks
     # re-inserting the prompt (or any of its prefixes) adds NOTHING
     assert t.insert(toks) == 0
     assert t.insert(toks[:100]) == 0
     assert t.n_cached_blocks == 12 and len(t._nodes) <= 2
     # a divergent prompt splits the edge; block accounting is unchanged
     other = toks[:64] + [250] * 64
-    t.insert(other, lambda s, e: {"s": s})
+    t.insert(other, lambda b, n: [{"b": i} for i in range(b, b + n)])
     assert t.n_cached_blocks == 12 + len(other) // BS - 4
     assert sum(len(n.block_ids) for n in t._nodes.values()) \
         == t.allocator.used_blocks
@@ -244,13 +246,14 @@ def test_no_placeholder_entries_leak_capacity():
 def test_eviction_never_frees_locked_blocks():
     t = RadixTree(capacity_blocks=8, block_size=BS)
     hot = list(range(0, 64))            # 4 blocks
-    t.insert(hot, lambda s, e: {"s": s})
+    t.insert(hot, lambda b, n: [{"b": i} for i in range(b, b + n)])
     m = t.match_blocks(hot + [1])       # uncapped full match of hot
     assert m.n_blocks == 4
     t.lock(m.nodes)
     # pool pressure: a 6-block insert can only take the 4 free blocks
     cold = [200 + i for i in range(96)]
-    stored = t.insert(cold, lambda s, e: {"s": s})
+    stored = t.insert(cold,
+                      lambda b, n: [{"b": i} for i in range(b, b + n)])
     assert stored == 4 and t.allocator.free_blocks == 0
     # locked path untouched, payloads still served
     m2 = t.match_blocks(hot + [1])
@@ -278,6 +281,176 @@ def test_hit_rate_statistics():
     assert m.n_blocks == 4
     assert t.n_queries == 2
     assert t.hit_rate == pytest.approx(4 / 8)
+
+
+def _accounting_state(payloads_fn=None):
+    """Two pod-registered trees of 8 blocks through inserts, re-inserts,
+    matches, edge splits and evictions: every stored count, node (id,
+    start, hashes, block ids, tick, hits, parent), allocator ownership
+    and directory publication."""
+    pod = PodKVDirectory(block_size=BS)
+    trees = [RadixTree(capacity_blocks=8, block_size=BS) for _ in range(2)]
+    for owner, t in enumerate(trees):
+        pod.register(owner, t)
+    rng = np.random.default_rng(7)
+    head = rng.integers(2, 60, 64).tolist()
+    stored = []
+    for k in range(8):
+        t = trees[k % 2]
+        toks = head[:16 * (k % 4 + 1)] + rng.integers(2, 60, 40).tolist()
+        stored.append(t.insert(toks, payloads_fn))
+        stored.append(t.match_blocks(toks[:70]).n_blocks)
+        stored.append(t.insert(toks[:50], payloads_fn))
+    nodes = [[(n.node_id, n.start, n.hashes, n.block_ids, n.tick, n.hits,
+               n.parent.node_id) for n in t._nodes.values()]
+             for t in trees]
+    owned = [dict(t.allocator._owned) for t in trees]
+    return stored, nodes, owned, pod._entries
+
+
+def test_accounting_insert_is_unchanged():
+    """An insert without a payload function (the simulator's TE prefix
+    directories, accounting-only trees) keeps every hash, block id, tick
+    and directory publication: the digest is that of the per-block
+    insert the batched one replaced, and a payload-bearing tree walks
+    the same states."""
+    import hashlib
+    state = _accounting_state()
+    assert hashlib.sha256(repr(state).encode()).hexdigest() == \
+        "a4f624e31fed77b3a6161359ea7a4852dc7f8e390ed95269f9fcf4fed256c430"
+    calls = []
+
+    def payloads(first, n):
+        calls.append((first, n))
+        return [{"b": b} for b in range(first, first + n)]
+    assert _accounting_state(payloads) == state
+    # one call per insert that stored blocks, none for the others
+    assert len(calls) == sum(1 for n in state[0][0::3] + state[0][2::3]
+                             if n)
+
+
+# ---------------------------------------------------------------------------
+# JAX backend: the insert's batched slicer (fast tier)
+# ---------------------------------------------------------------------------
+def _random_cache(m, length, seed=0):
+    """A batch-1 cache of the model's layout, filled with noise."""
+    import jax
+    leaves, treedef = jax.tree.flatten(m.init_cache(1, length))
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree.unflatten(treedef, [
+        jax.random.normal(k, x.shape).astype(x.dtype)
+        for k, x in zip(keys, leaves)])
+
+
+@pytest.mark.parametrize("first,n", [(0, 1), (0, 3), (3, 1), (2, 3),
+                                     (4, 3), (6, 1)])
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "deepseek-v3-671b"])
+def test_batched_slice_matches_per_block_slices(make_model, arch, first,
+                                                n):
+    """Each payload of the one-program slicer is bit-identical, in
+    pytree, shape and dtype, to the eager per-block slice, for GQA
+    (k, v) and MLA (ckv, krope) caches. The 120-token buffer holds 7
+    whole blocks and an 8-token tail: (4, 3) and (6, 1) end at its last
+    whole block. The payloads are buffers of their own: they survive
+    the cache's deletion, as they must survive its donation."""
+    import jax
+    from repro.serving.backend import JAXBackend
+    from repro.xccl.pd_transfer import slice_kv_chunk
+    _, m, params = make_model(arch)
+    be = JAXBackend(m, params, max_len=256)
+    cache = _random_cache(m, 120)
+    want = [jax.tree.map(np.asarray,
+                         slice_kv_chunk(cache, b * BS, (b + 1) * BS))
+            for b in range(first, first + n)]
+    got = be.slice_prefill_blocks(cache, [], first, n, BS)
+    single = be.slice_prefill_kv(cache, [], first * BS, (first + 1) * BS)
+    for leaf in jax.tree.leaves(cache):
+        leaf.delete()
+    assert len(got) == n
+    for w, g in zip(want + want[:1], got + [single]):
+        assert jax.tree.structure(g) == jax.tree.structure(w)
+
+        def same(a, b):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(a, np.asarray(b))
+        jax.tree.map(same, w, g)
+
+
+def test_batched_slice_refuses_ranges_that_are_no_blocks(make_model):
+    from repro.serving.backend import JAXBackend
+    _, m, params = make_model("internlm2-1.8b")
+    be = JAXBackend(m, params, max_len=256)
+    cache = _random_cache(m, 120)
+    with pytest.raises(ValueError, match="whole blocks"):
+        be.slice_prefill_blocks(cache, [], 6, 2, BS)   # past block 7
+    with pytest.raises(ValueError, match="not a block"):
+        be.slice_prefill_kv(cache, [], 8, 24)          # straddles two
+
+
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "deepseek-v3-671b"])
+def test_seed_from_batched_insert_is_bit_identical(make_model, arch):
+    """The TestJAXBitIdentity check driven through DPGroup: the
+    provider's prefill stores its blocks by the batched insert, the
+    consumer's prefill hits them, seeds and runs the suffix; its logits
+    and the valid region of its KV equal a cold chunked prefill's."""
+    import jax
+    from repro.serving.backend import JAXBackend
+    from repro.serving.dp_group import DPGroup
+    from repro.serving.request import Request
+    from repro.serving.scheduler import ChunkWork
+    from repro.xccl.pd_transfer import slice_kv_chunk
+    _, m, params = make_model(arch)
+    be = JAXBackend(m, params, max_len=256)
+    dp = DPGroup(0, be, max_batch=2, max_len=256)
+    try:
+        rng = np.random.default_rng(2)
+        prefix = rng.integers(2, 60, 48).tolist()               # 3 blocks
+        provider = prefix + rng.integers(2, 60, 10).tolist()    # bucket 64
+        consumer = prefix + rng.integers(2, 60, 70).tolist()    # bucket 128
+        pr = Request(prompt_tokens=provider, max_new_tokens=4)
+        assert dp.run_prefill_chunk(ChunkWork(pr, 0, len(provider)))
+        assert dp.prefix_cache.n_cached_blocks == 3
+        cr = Request(prompt_tokens=list(consumer), max_new_tokens=4)
+        cache_s, log_s = dp.run_prefill_chunk(
+            ChunkWork(cr, 0, len(consumer)))
+        assert cr.prefix_hit_tokens == 48
+        assert dp.prefix_cache.n_cached_blocks == len(consumer) // BS
+        cache_c, log_c = be.prefill_chunk(None, consumer, 0, len(consumer))
+        np.testing.assert_array_equal(np.asarray(log_c), log_s)
+        kv_c = jax.tree.map(np.asarray,
+                            slice_kv_chunk(cache_c, 0, len(consumer)))
+        kv_s = jax.tree.map(np.asarray,
+                            slice_kv_chunk(cache_s, 0, len(consumer)))
+        jax.tree.map(np.testing.assert_array_equal, kv_c, kv_s)
+    finally:
+        dp.close()
+
+
+def test_slicer_compiles_once_per_cache_shape(make_model):
+    """Prompts of several lengths in one buffer bucket, inserted by one
+    DP group and then by a second group's own backend, share one
+    executable of the module-level slicer."""
+    from repro.serving.backend import JAXBackend
+    from repro.serving.dp_group import DPGroup
+    from repro.serving.request import Request
+    from repro.serving.scheduler import ChunkWork
+    from repro.xccl.pd_transfer import slice_kv_blocks
+    _, m, params = make_model("internlm2-1.8b")
+    dps = [DPGroup(i, JAXBackend(m, params, max_len=256), max_batch=2,
+                   max_len=256) for i in range(2)]
+    slice_kv_blocks.clear_cache()
+    try:
+        rng = np.random.default_rng(4)
+        for dp in dps:
+            for n in (70, 97, 128):                        # bucket 128
+                r = Request(prompt_tokens=rng.integers(2, 60, n).tolist(),
+                            max_new_tokens=4)
+                assert dp.run_prefill_chunk(ChunkWork(r, 0, n))
+            assert dp.prefix_cache.n_cached_blocks == (70 + 97 + 128) // BS
+            assert slice_kv_blocks._cache_size() == 1
+    finally:
+        for dp in dps:
+            dp.close()
 
 
 # ---------------------------------------------------------------------------

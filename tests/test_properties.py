@@ -415,7 +415,8 @@ def test_prefix_cache_exact_hit_semantics(toks):
     never matches past the common prefix."""
     from repro.serving.kv_cache import PrefixCache
     pc = PrefixCache(capacity_blocks=64, block_size=16)
-    stored = pc.insert(toks, lambda s, e: {"start": s})
+    stored = pc.insert(
+        toks, lambda b, n: [{"start": i * 16} for i in range(b, b + n)])
     n_full = len(toks) // 16
     assert stored == n_full
     if n_full:

@@ -83,3 +83,38 @@ def test_the_buffer_is_bounded_and_selects_by_time(profiling):
     assert rec.between(mid.end_ns, mid.end_ns + 1) == []
     rec.clear()
     assert rec.between(*EVERYTHING) == []
+
+
+def test_an_insert_records_one_slice_span(make_model, tmp_path):
+    """The radix insert slices every new block in one backend dispatch:
+    one ``backend.prefill.slice`` under ``dp.prefill.insert``, counting
+    the blocks the program materialises (the buffer's whole blocks),
+    while the insert counts the blocks it stored."""
+    import numpy as np
+
+    from repro.serving.backend import JAXBackend
+    from repro.serving.dp_group import DPGroup
+    _, m, params = make_model("internlm2-1.8b")
+    be = JAXBackend(m, params, max_len=256)
+    dp = DPGroup(0, be, max_batch=2, max_len=256)
+    try:
+        toks = np.random.default_rng(0).integers(2, 60, 90).tolist()
+        dp._cache_insert(toks[:40], be.prefill_chunk(None, toks[:40], 0,
+                                                     40)[0])
+        cache, _ = be.prefill_chunk(None, toks, 0, len(toks))  # 128 long
+        be.slice_prefill_blocks(cache, toks, 0, 1, 16)          # compile
+        RECORDER.clear()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            dp._cache_insert(toks, cache)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        dp.close()
+    got = RECORDER.between(*EVERYTHING)
+    assert [s.name for s in got] == ["backend.prefill.slice",
+                                     "dp.prefill.insert"]
+    sl, ins = got
+    assert sl.parent == "dp.prefill.insert"
+    assert sl.n == 128 // 16            # the buffer's whole blocks
+    assert ins.n == 90 // 16 - 40 // 16  # the blocks not yet cached
